@@ -55,7 +55,6 @@ if __name__ == "__main__" and "XLA_FLAGS" not in os.environ:
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -87,10 +86,10 @@ def measure_hidden_fraction(*, M: int = 256, K: int = 4096, N: int = 512,
     A = jnp.asarray(rng.standard_normal((M, K)), jnp.float32)
     W = jnp.asarray(rng.standard_normal((K, N)), jnp.float32)
 
-    gather = jax.jit(shard_map(
+    gather = jax.jit(jax.shard_map(
         lambda x: jax.lax.all_gather(x, "x", axis=1, tiled=True),
         mesh=mesh, in_specs=P(None, "x"), out_specs=P(None, None),
-        check_rep=False))
+        check_vma=False))
     # the compute half lives on the default device, off the mesh, so the
     # runtime is free to execute it while the gather is in flight
     gemm = jax.jit(lambda a, w: jnp.dot(a, w))

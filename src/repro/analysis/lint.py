@@ -35,7 +35,8 @@ mechanical checks:
     are extracted from each kernel's ``pallas_call`` declaration by AST
     and evaluated against every VMEM-feasible candidate the autotuner can
     emit for the paper shapes; (working set x 2 for double buffering)
-    must fit the arch's GB (VMEM) capacity.  An un-evaluatable
+    must fit the scoped VMEM limit every kernel requests from Mosaic
+    (``kernels/autotune.py::VMEM_BUDGET``).  An un-evaluatable
     declaration is itself a finding — the extraction must not silently
     rot.
 
@@ -463,11 +464,12 @@ def _kernel_vmem_cases() -> Dict[str, Tuple[List[Dict[str, int]], str]]:
 
 def vmem_findings(root: Optional[Path] = None) -> List[LintFinding]:
     """Static VMEM working-set check of every kernel's pallas_call
-    declaration against the arch GB capacity, across all autotuner-
-    feasible candidate blocks for the paper shapes."""
-    from repro.core.hardware import tpu_v5e
+    declaration against the scoped VMEM limit the kernels request
+    (``autotune.VMEM_BUDGET``), across all autotuner-feasible candidate
+    blocks for the paper shapes."""
+    from repro.kernels.autotune import VMEM_BUDGET
     root = root or _PKG_ROOT
-    capacity = tpu_v5e().gb.size_bytes
+    capacity = VMEM_BUDGET
     block_bytes = 2  # kernels take/emit bf16 blocks; scratch dtype is read
     out: List[LintFinding] = []
     for rel, (envs, label) in _kernel_vmem_cases().items():
@@ -511,7 +513,8 @@ def vmem_findings(root: Optional[Path] = None) -> List[LintFinding]:
                 out.append(LintFinding(
                     "vmem-budget", rel, decl["line"], 0,
                     f"declared working set {worst[0]} B x2 (double "
-                    f"buffer) exceeds GB capacity {capacity} B for "
+                    f"buffer) exceeds the kernels' scoped VMEM limit "
+                    f"{capacity} B for "
                     f"candidate {worst[1]} ({label})"))
     return out
 

@@ -20,16 +20,10 @@ import json
 import os
 import sys
 
-# XLA only reads XLA_FLAGS at backend initialization — nothing has
-# triggered that yet even though `-m` imported the package __init__ —
-# so setting the forced device count here still takes effect.
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+from repro.core import hardware
 
-from repro.core import hardware  # noqa: E402
-
-from .driver import calibrate_once  # noqa: E402
-from .harness import (SweepConfig, _replace_mesh, jax_measure_fn,  # noqa: E402
+from .driver import calibrate_once
+from .harness import (SweepConfig, _replace_mesh, jax_measure_fn,
                       synthetic_measure_fn)
 
 PRESETS = {"edge": hardware.edge, "cloud": hardware.cloud,
@@ -79,7 +73,17 @@ def main(argv=None) -> int:
 
     reference = PRESETS[args.arch]().cluster_noc
     if args.backend == "cpu":
+        # Nothing above imports jax, so the CPU platform and its forced
+        # device count are set before the backend starts: on a host with
+        # an accelerator the sweep would otherwise time the accelerator
+        # and persist it under provenance backend "cpu".
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ.setdefault("XLA_FLAGS",
+                              "--xla_force_host_platform_device_count=8")
         import jax
+        if jax.devices()[0].platform != "cpu":
+            ap.error("--backend=cpu: jax was started on "
+                     f"{jax.devices()[0].platform} before this CLI ran")
         n = len(jax.devices())
         reference = _replace_mesh(reference, (1, n))
         measure_fn = jax_measure_fn()

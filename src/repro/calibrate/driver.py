@@ -20,6 +20,7 @@ reuse semantics the acceptance criteria pin down:
 from __future__ import annotations
 
 import time
+from collections import Counter
 from typing import Callable, Dict, Optional
 
 from repro.core.hardware import NoCParams
@@ -35,6 +36,10 @@ __all__ = ["calibrate_once"]
 def _params_json(p: NoCParams) -> Dict:
     return {"mesh": list(p.mesh), "channel_bandwidth": p.channel_bandwidth,
             "t_router": p.t_router, "t_enq": p.t_enq}
+
+
+def _points_by_type(points) -> Dict[str, int]:
+    return dict(sorted(Counter(p.col_type for p in points).items()))
 
 
 def calibrate_once(
@@ -70,6 +75,7 @@ def calibrate_once(
                 "path": str(path),
                 "backend": backend,
                 "n_points": len(cached.points),
+                "points_by_type": _points_by_type(cached.points),
                 "n_dropped": 0,
                 "degenerate": bool(cached.provenance.get("degenerate",
                                                          False)),
@@ -107,6 +113,7 @@ def calibrate_once(
         "path": str(persisted_path) if persisted_path else None,
         "backend": backend,
         "n_points": fit.n_points,
+        "points_by_type": _points_by_type(sweep.points),
         "n_dropped": sweep.n_dropped,
         "dropped": dict(sweep.dropped),
         "degenerate": fit.degenerate,

@@ -39,15 +39,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; support
-# both, and older releases lack the has_side_effects knob (it only guards
-# the DMA-only kernel against DCE; the output here data-depends on every
-# copy, so omitting it is safe).
-_CP = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-try:
-    _COMPILER_PARAMS = _CP(has_side_effects=True)
-except TypeError:  # pragma: no cover - version compat
-    _COMPILER_PARAMS = _CP()
+from .autotune import VMEM_BUDGET
+
+# has_side_effects only guards the DMA-only kernel body against DCE; the
+# output here data-depends on every copy.
+_COMPILER_PARAMS = pltpu.CompilerParams(has_side_effects=True,
+                                        vmem_limit_bytes=VMEM_BUDGET)
 
 # Static VMEM budget envelope: the (M, K, N, chunks) configurations the
 # tests and the overlap microbenchmark drive the kernel with.  The
@@ -58,12 +55,8 @@ BUDGET_SHAPES = (
     (256, 4096, 512, 8),   # overlap_bench.measure_hidden_fraction scale
     (128, 1024, 256, 8),   # overlap_bench.measure_double_buffer
     (128, 512, 256, 4),    # test_kernels streamed-GEMM cases (largest)
+    (256, 4096, 512, 4),   # chip_smoke.py --chips 4 (one chunk per chip)
 )
-# ... and TPUMemorySpace.ANY -> MemorySpace.ANY.
-_ANY = getattr(pltpu, "ANY", None)
-if _ANY is None:  # pragma: no cover - version compat
-    _ANY = pltpu.MemorySpace.ANY
-
 __all__ = ["streamed_gemm", "allgather_gemm", "allgather_gemm_reference"]
 
 
@@ -147,8 +140,8 @@ def streamed_gemm(x: jax.Array, w: jax.Array, *, chunks: int,
     return pl.pallas_call(
         functools.partial(_kernel, n_chunks=chunks, kc=kc, nbuf=buffers),
         in_specs=[
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[

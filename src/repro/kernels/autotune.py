@@ -44,8 +44,12 @@ __all__ = ["attention_blocks", "gemm_epilogue_blocks", "ssd_chunk_len",
            "VMEM_BUDGET", "PAPER_KERNEL_SHAPES", "plan_jobs",
            "attention_plan_job", "gemm_epilogue_plan_job", "ssd_plan_jobs"]
 
-# usable VMEM per core for kernel working sets (half of 128 MB, leaving room
-# for Pallas double buffering which the cost model assumes)
+# The scoped VMEM every kernel asks Mosaic for (CompilerParams
+# vmem_limit_bytes) and the bound the candidate filters below keep each
+# working-set estimate x2 (Pallas double buffering) within: half of v5e's
+# 128 MiB.  Mosaic's default scoped limit is 16 MiB, which refuses blocks
+# this filter passes; tests/test_tpu_compile.py compiles every paper shape
+# at its chosen blocks against this limit.
 VMEM_BUDGET = 64 * 1024 * 1024
 _LANE = 128  # MXU/VPU lane alignment
 
@@ -267,7 +271,10 @@ def ssd_chunk_len(s: int, p: int, n: int) -> int:
     if not jobs:
         return 128
     cache = get_plan_cache()
-    cache.warmup([(co, arch, kw) for co, arch, kw, _c in jobs])
+    # threads, not the process pool: this runs inside kernel tracing, in
+    # a process that may hold the accelerator
+    cache.warmup([(co, arch, kw) for co, arch, kw, _c in jobs],
+                 executor="thread")
     best = None
     for co, arch, kw, c in jobs:
         plan = cache.resolve(co, arch, **kw)

@@ -24,8 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from .autotune import VMEM_BUDGET, attention_blocks
 
 __all__ = ["flash_attention_fwd", "flash_attention"]
 
@@ -100,8 +99,6 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         block_k: Optional[int] = None,
                         interpret: Optional[bool] = None) -> jax.Array:
     """Pallas forward.  q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D)."""
-    from .autotune import attention_blocks
-
     B, Hq, Sq, D = q.shape
     Hkv, Skv = k.shape[1], k.shape[2]
     assert Hq % Hkv == 0, (Hq, Hkv)
@@ -151,8 +148,9 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_BUDGET),
         interpret=interpret,
     )(qr, kr, vr)
     out = out.reshape(B, Hq, Sqp, D)

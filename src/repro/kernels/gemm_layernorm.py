@@ -17,8 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from .autotune import VMEM_BUDGET, gemm_epilogue_blocks
 
 __all__ = ["gemm_layernorm", "gemm_rmsnorm"]
 
@@ -56,8 +55,6 @@ def _kernel(a_ref, b_ref, g_ref, beta_ref, o_ref, acc, *, eps: float,
 
 def _fused_gemm_norm(a, b, gamma, beta, *, eps, rms, block_m, block_k,
                      interpret):
-    from .autotune import gemm_epilogue_blocks
-
     M, K = a.shape
     K2, N = b.shape
     assert K == K2 and gamma.shape == (N,)
@@ -87,8 +84,9 @@ def _fused_gemm_norm(a, b, gamma, beta, *, eps, rms, block_m, block_k,
         out_specs=pl.BlockSpec((block_m, N), lambda mi, ki: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, N), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_BUDGET),
         interpret=interpret,
     )(ap, bp, g2, beta2)
     return out[:M] if pm else out

@@ -18,8 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from .autotune import VMEM_BUDGET, gemm_epilogue_blocks
 
 __all__ = ["gemm_softmax"]
 
@@ -51,8 +50,6 @@ def gemm_softmax(a: jax.Array, b: jax.Array, *,
                  block_k: Optional[int] = None,
                  interpret: Optional[bool] = None) -> jax.Array:
     """softmax(a @ b, axis=-1); a: (M, K), b: (K, N)."""
-    from .autotune import gemm_epilogue_blocks
-
     M, K = a.shape
     K2, N = b.shape
     assert K == K2
@@ -78,8 +75,9 @@ def gemm_softmax(a: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec((block_m, N), lambda mi, ki: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, N), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_BUDGET),
         interpret=interpret,
     )(ap, bp)
     return out[:M] if pm else out

@@ -11,6 +11,12 @@ y_t = C_t · h_t,   h_t = exp(dA_t) · h_{t-1} + B_t ⊗ xdt_t
 
 Inputs (per flattened batch*heads row):
   xdt (BH, S, P)  dA (BH, S)  B (BH, S, N)  C (BH, S, N)
+
+The within-chunk cumulative log decay is computed outside the kernel and
+handed in twice, as a column (BH, S, 1) and as rows (BH, nc, 1, chunk):
+both block shapes meet Mosaic's tiling rule (the last two block dims are
+multiples of (8, 128) or equal the array's), and the kernel needs no
+in-VMEM cumsum or (c, 1) -> (1, c) relayout.
 """
 from __future__ import annotations
 
@@ -22,13 +28,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from .autotune import VMEM_BUDGET, ssd_chunk_len
 
 __all__ = ["ssd_scan_fwd", "ssd_scan"]
 
 
-def _ssd_kernel(xdt_ref, da_ref, b_ref, c_ref, y_ref, h_scr, *, chunk: int):
+def _ssd_kernel(xdt_ref, cs_col_ref, cs_row_ref, b_ref, c_ref, y_ref, h_scr,
+                *, chunk: int):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
@@ -36,16 +42,17 @@ def _ssd_kernel(xdt_ref, da_ref, b_ref, c_ref, y_ref, h_scr, *, chunk: int):
         h_scr[...] = jnp.zeros_like(h_scr)
 
     x = xdt_ref[0].astype(jnp.float32)                 # (c, P)
-    da = da_ref[0].astype(jnp.float32)                 # (1, c) block
+    csr = cs_col_ref[0]                                # (c, 1) cumsum of dA
+    csc = cs_row_ref[0, 0]                             # (1, c) same, as a row
     bmat = b_ref[0].astype(jnp.float32)                # (c, N)
     cmat = c_ref[0].astype(jnp.float32)                # (c, N)
-
-    cs = jnp.cumsum(da, axis=-1)                       # (1, c)
-    csr = cs.reshape(chunk, 1)                         # (c, 1)
-    total = cs[0, chunk - 1]
+    # chunk total cs[c-1] as a (1, 1) reduction: a lane slice of csc keeps
+    # its lane offset, which Mosaic cannot broadcast down the (c, 1) column
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    total = jnp.sum(jnp.where(last, csc, 0.0), axis=1, keepdims=True)
 
     # intra-chunk: (C B^T * L) @ X with L[i,j] = exp(cs_i - cs_j) for i>=j
-    logl = csr - csr.reshape(1, chunk)                 # (c, c)
+    logl = csr - csc                                   # (c, c)
     i_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     j_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     lmask = i_idx >= j_idx
@@ -74,8 +81,6 @@ def ssd_scan_fwd(xdt: jax.Array, dA: jax.Array, B: jax.Array, C: jax.Array,
                  *, chunk: Optional[int] = None,
                  interpret: Optional[bool] = None) -> jax.Array:
     """Pallas forward. Shapes: xdt (BH,S,P), dA (BH,S), B/C (BH,S,N)."""
-    from .autotune import ssd_chunk_len
-
     BH, S, P = xdt.shape
     N = B.shape[-1]
     if interpret is None:
@@ -88,24 +93,29 @@ def ssd_scan_fwd(xdt: jax.Array, dA: jax.Array, B: jax.Array, C: jax.Array,
         B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
     Sp = S + pad
-    da3 = dA.reshape(BH, Sp // chunk, chunk)           # (BH, nc, c): chunk-blocked
+    nc = Sp // chunk
+    cs = jnp.cumsum(dA.astype(jnp.float32).reshape(BH, nc, chunk), axis=-1)
+    cs_col = cs.reshape(BH, Sp, 1)
+    cs_row = cs.reshape(BH, nc, 1, chunk)
 
     out = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=chunk),
-        grid=(BH, Sp // chunk),
+        grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bh, ci: (bh, ci, 0)),
+            pl.BlockSpec((1, chunk, 1), lambda bh, ci: (bh, ci, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda bh, ci: (bh, ci, 0, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, P), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sp, P), xdt.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_BUDGET),
         interpret=interpret,
-    )(xdt, da3, B, C)
+    )(xdt, cs_col, cs_row, B, C)
     return out[:, :S] if pad else out
 
 

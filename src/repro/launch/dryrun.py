@@ -124,8 +124,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
     rec["lower_compile_s"] = round(time.time() - t0, 1)
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax returns [per-device dict]
-        ca = ca[0] if ca else {}
     rec["cost_analysis"] = {k: float(v) for k, v in ca.items()
                             if isinstance(v, (int, float))
                             and k in ("flops", "bytes accessed",

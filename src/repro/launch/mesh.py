@@ -10,31 +10,21 @@ state (the dry-run must set XLA_FLAGS before any jax initialization).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh", "make_auto_mesh",
-           "POD_SHAPE"]
+__all__ = ["make_production_mesh", "make_host_mesh", "POD_SHAPE"]
 
 POD_SHAPE = (16, 16)
-
-
-def make_auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    """jax.make_mesh with Auto axis types on jax versions that have them
-    (jax.sharding.AxisType landed after 0.4.x; Auto is the old implicit
-    behavior, so omitting it there is equivalent)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: Optional[int] = None) -> jax.sharding.Mesh:

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import Model
 from repro.parallel.sharding import (batch_sharding, param_shardings,
@@ -161,6 +162,7 @@ def main() -> None:
 
     if args.plan_cache:
         os.environ["REPRO_PLAN_CACHE"] = args.plan_cache
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = Model(cfg)
     mesh = None

@@ -135,7 +135,6 @@ def moe_apply(cfg: ModelConfig, p: Dict, x: jax.Array,
     else:
         import math as _math
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         ep = mesh.shape["model"]
         assert E % ep == 0, (E, ep)
         e_local = E // ep
@@ -161,7 +160,7 @@ def moe_apply(cfg: ModelConfig, p: Dict, x: jax.Array,
             return jax.lax.psum(y, "model")
 
         rbias = p.get("router_bias")
-        out = shard_map(
+        out = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(dp_axes if dp_axes else None, None),
                       P(None, None),
@@ -169,7 +168,7 @@ def moe_apply(cfg: ModelConfig, p: Dict, x: jax.Array,
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),
             out_specs=P(dp_axes if dp_axes else None, None),
-            check_rep=False,
+            check_vma=False,
         )(x2d, p["router"], rbias if rbias is not None else
           jnp.zeros((E,), F32), p["wi"], p["wg"], p["wo"])
 

@@ -39,11 +39,18 @@ def _init_one(spec: ParamSpec, key: jax.Array) -> jax.Array:
     return (jax.random.normal(key, spec.shape, jnp.float32) * std).astype(dt)
 
 
+# One compiled program per leaf: the draw, scale and cast fuse, so a
+# bf16 leaf never has f32 copies beside it — op by op, the largest leaf
+# of a full-width model briefly holds three, which one 16 GB chip has no
+# room for next to the leaves already made.
+_init_one_jit = jax.jit(_init_one, static_argnums=0)
+
+
 def init_tree(specs, key: jax.Array):
     """Initialize a pytree of arrays from a pytree of ParamSpecs."""
     leaves, treedef = jax.tree.flatten(specs, is_leaf=lambda x: isinstance(x, ParamSpec))
     keys = jax.random.split(key, len(leaves))
-    return jax.tree.unflatten(treedef, [_init_one(s, k) for s, k in zip(leaves, keys)])
+    return jax.tree.unflatten(treedef, [_init_one_jit(s, k) for s, k in zip(leaves, keys)])
 
 
 def abstract_tree(specs):

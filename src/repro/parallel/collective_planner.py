@@ -20,7 +20,6 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.collectives import collective_cost, noc_latency
@@ -162,11 +161,11 @@ def sharded_softmax_xent(h: jax.Array, unembed: jax.Array,
 
     fn = dist_fn if strategy == "dist" else gather_fn
     dp_spec = dp if len(dp) > 1 else (dp[0] if dp else None)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(dp_spec, None, None), P(None, mdl), P(dp_spec, None)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(h, unembed, labels)
 
 

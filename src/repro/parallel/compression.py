@@ -16,7 +16,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 F32 = jnp.float32
@@ -57,5 +56,5 @@ def compressed_psum(x: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
     in_spec = P(*([axis] + [None] * (x.ndim - 1)))
     # shard over the reduced axis on dim 0 requires divisibility; fall back
     # to replicated input (each shard holds a full copy == grad replicas).
-    return shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
-                     check_rep=False)(x)
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(x)

@@ -75,13 +75,19 @@ class ServeEngine:
         self.default_deadline_s = default_deadline_s
         cfg = model.cfg
 
-        self._prefill = jax.jit(
+        # the engine's two compiled steps (public so a caller can lower
+        # and inspect the exact programs the engine runs)
+        self.prefill_step = jax.jit(
             lambda p, b: model.prefill(p, b, cache_len, mesh))
-        self._decode = jax.jit(
+        self.decode_step = jax.jit(
             lambda p, c, t: model.decode(p, c, t, mesh),
             donate_argnums=(1,))
+        # nonfinite_tokens: tokens taken (by argmax) from a logits row
+        # that held a NaN or an inf; argmax still yields an in-vocabulary
+        # id there, so only this count shows a broken decode step
         self.stats: Dict[str, float] = {"prefill_calls": 0, "decode_steps": 0,
-                                        "tokens_out": 0, "timeouts": 0}
+                                        "tokens_out": 0, "timeouts": 0,
+                                        "nonfinite_tokens": 0}
         if plan_warmup:
             self.warm_plans()
 
@@ -111,12 +117,15 @@ class ServeEngine:
         """Pre-solve the block-selection plans for this engine's kernel
         shapes in one ``search_many`` sweep and persist them (PlanCache
         disk store), so neither this process nor any later one re-solves
-        at trace time."""
+        at trace time.  The sweep runs on threads: a serving process
+        holds the accelerator, and a forked pool worker would inherit
+        its runtime (the sweep is a handful of jobs anyway)."""
         from ..kernels.autotune import plan_jobs
         from ..core.plan import get_plan_cache
 
         t0 = time.time()
         stats = get_plan_cache().warmup(plan_jobs(self.plan_shapes()),
+                                        executor="thread",
                                         sweep_id="serve-warmup")
         self.stats["plan_warmup_hits"] = stats["hits"]
         self.stats["plan_warmup_solved"] = stats["solved"]
@@ -143,7 +152,7 @@ class ServeEngine:
             Se = max(1, self.prompt_len // self.model.cfg.enc_ratio)
             batch["src_embeds"] = jnp.zeros(
                 (self.B, Se, self.model.cfg.d_model), jnp.float32)
-        logits, cache = self._prefill(self.params, batch)
+        logits, cache = self.prefill_step(self.params, batch)
         self.stats["prefill_calls"] += 1
         last = jnp.argmax(logits[:, -1, :self.model.cfg.vocab_size], -1)
         cache = dict(cache)
@@ -204,16 +213,18 @@ class ServeEngine:
             if all(r is None or r.done for r in active) and not queue:
                 break
             tok = last[:, None].astype(jnp.int32)
-            logits, cache = self._decode(self.params, cache, tok)
+            logits, cache = self.decode_step(self.params, cache, tok)
             self.stats["decode_steps"] += 1
-            last = jnp.argmax(logits[:, -1, :self.model.cfg.vocab_size], -1)
-            host = np.asarray(last)
+            step = logits[:, -1, :self.model.cfg.vocab_size]
+            last = jnp.argmax(step, -1)
+            host, finite = jax.device_get((last, jnp.isfinite(step).all(-1)))
             now = time.monotonic()
             for i, r in enumerate(active):
                 if r is None or r.done:
                     continue
                 r.output.append(int(host[i]))
                 self.stats["tokens_out"] += 1
+                self.stats["nonfinite_tokens"] += int(not finite[i])
                 deadline = (r.deadline_s if r.deadline_s is not None
                             else self.default_deadline_s)
                 if deadline is not None and now - admitted[i] >= deadline:

@@ -26,8 +26,9 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from repro.launch.mesh import make_auto_mesh
-    mesh = make_auto_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     assert len(jax.devices()) == 8
 
     # ---- 1. sharded train step on the mesh, GSPMD loss

@@ -181,7 +181,6 @@ def test_allgather_gemm_matches_reference_on_mesh():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.kernels.allgather_gemm import (allgather_gemm,
                                                   allgather_gemm_reference)
@@ -190,15 +189,15 @@ def test_allgather_gemm_matches_reference_on_mesh():
         X = jnp.asarray(rng.standard_normal((64, 512)), jnp.float32)
         W = jnp.asarray(rng.standard_normal((512, 128)), jnp.float32)
         for nbuf in (1, 2):
-            fused = shard_map(
+            fused = jax.shard_map(
                 lambda x, w: allgather_gemm(x, w, axis_name="x",
                                             buffers=nbuf),
                 mesh=mesh, in_specs=(P(None, "x"), P()),
-                out_specs=P(None, None), check_rep=False)
-            ref = shard_map(
+                out_specs=P(None, None), check_vma=False)
+            ref = jax.shard_map(
                 lambda x, w: allgather_gemm_reference(x, w, axis_name="x"),
                 mesh=mesh, in_specs=(P(None, "x"), P()),
-                out_specs=P(None, None), check_rep=False)
+                out_specs=P(None, None), check_vma=False)
             err = float(jnp.abs(fused(X, W) - ref(X, W)).max())
             assert err < 1e-3, (nbuf, err)
             print("AG_GEMM_OK", nbuf, err)

@@ -85,7 +85,6 @@ def test_cond_counts_max_branch():
 def test_collective_count_scan_multiplier():
     """A psum inside a scan inside a shard_map is counted scan-length
     times (and classified as an AllReduce)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.analysis import trace_counts
 
@@ -97,8 +96,8 @@ def test_collective_count_scan_multiplier():
         out, _ = jax.lax.scan(step, jnp.zeros_like(xs[0]), xs)
         return out
 
-    f = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                  check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                      check_vma=False)
     tc = trace_counts(f, jax.ShapeDtypeStruct((5, 8), jnp.float32))
     recs = list(tc.collectives.values())
     assert len(recs) == 1

@@ -47,6 +47,29 @@ def test_serve_engine_continuous_batching():
     assert eng.stats["prefill_calls"] == 3
 
 
+@pytest.mark.parametrize("poison", [False, True])
+def test_serve_engine_counts_nonfinite_tokens(poison):
+    """argmax over a NaN logits row still returns an in-vocabulary id, so
+    the engine counts every token taken from a non-finite row."""
+    from repro.serve.engine import Request, ServeEngine
+    cfg = get_smoke_config("glm4-9b")
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    if poison:
+        params = jax.tree.map(lambda x: x * jnp.nan if jnp.issubdtype(
+            x.dtype, jnp.floating) else x, params)
+    rng = np.random.default_rng(0)
+    reqs = [Request(rid=i, prompt=rng.integers(
+        0, cfg.vocab_size, size=12).astype(np.int32), max_new_tokens=3)
+        for i in range(2)]
+    eng = ServeEngine(model, params, batch_size=2, cache_len=32,
+                      prompt_len=16, plan_warmup=False)
+    done = eng.run(reqs)
+    assert all(len(r.output) == 3 for r in done)
+    assert eng.stats["tokens_out"] == 6
+    assert eng.stats["nonfinite_tokens"] == (6 if poison else 0)
+
+
 def test_serve_engine_refill_matches_serial_decoding():
     """Regression for the continuous-batching bug: slots refilled
     mid-decode used to inherit the previous occupant's KV cache and last

@@ -110,13 +110,12 @@ def test_psum_scatter_binds_reduce_scatter_primitive():
         "'--xla_force_host_platform_device_count=4'\n"
         "import jax, jax.numpy as jnp, numpy as np\n"
         "from jax.sharding import Mesh, PartitionSpec as P\n"
-        "from jax.experimental.shard_map import shard_map\n"
         "from repro.analysis import trace_counts\n"
         "mesh = Mesh(np.array(jax.devices()).reshape(4), ('model',))\n"
         "def body(x):\n"
         "    return jax.lax.psum_scatter(x, 'model', tiled=True)\n"
-        "f = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P('model'),\n"
-        "              check_rep=False)\n"
+        "f = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P('model'),\n"
+        "                  check_vma=False)\n"
         "tc = trace_counts(f, jax.ShapeDtypeStruct((8, 4), jnp.float32))\n"
         "rec = tc.collectives.get(('ReduceScatter', 4))\n"
         "assert rec is not None, tc.to_dict()\n"
