@@ -233,7 +233,10 @@ def serve_phase(seed: int) -> bool:
         # NaN row still gives an in-vocabulary token
         nonfinite = summary["nonfinite_tokens"]
         _line(path=path, prefill_compile_s=f"{compile_s:.1f}",
-              wall_s=summary["wall_s"], completed=f"{len(done)}/{len(reqs)}",
+              wall_s_with_compile=summary["wall_s"],
+              serve_tok_s=summary["serve_tok_s"],
+              ttft_p50_ms=summary["ttft_p50_ms"], compiles=summary["compiles"],
+              completed=f"{len(done)}/{len(reqs)}",
               tokens=summary["tokens"], nonfinite_tokens=nonfinite,
               tpu_custom_call=has_kernel,
               prefill_logits_finite=bool(np.isfinite(logits[path]).all()),

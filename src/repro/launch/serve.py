@@ -61,18 +61,31 @@ def build(cfg: ModelConfig, *, requests: int, batch: int, prompt_len: int,
 
 
 def run(eng: ServeEngine, reqs: List[Request]) -> Dict:
-    """Serve ``reqs`` to completion; returns the run's counts and its
-    host wall time."""
-    t0 = time.time()
+    """Serve ``reqs`` to completion; returns the run's counts and rates.
+
+    ``serve_tok_s`` and ``ttft_p50_ms`` come from the engine's own request
+    stamps: tokens over the first admission to the last finish, and the
+    median admission-to-first-token time.  ``wall_s`` is the host time of
+    the whole call, compilation included (``compiles`` counts the
+    executables built while serving)."""
+    compiles0 = eng.stats["compiles"]
+    t0 = time.perf_counter()
     done = eng.run(reqs)
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     n_tok = sum(len(r.output) for r in done)
+    served = [r for r in done if r.first_token_s is not None]
+    ends = [r.done_s for r in served if r.done_s is not None]
+    span = max(ends) - min(r.admitted_s for r in served) if ends else 0.0
     return {
         "requests": len(done),
         "completed": sum(r.done or len(r.output) > 0 for r in done),
         "tokens": n_tok,
         "wall_s": round(dt, 2),
-        "tok_per_s": round(n_tok / dt, 1),
+        "serve_tok_s": round(n_tok / span, 1) if span > 0 else None,
+        "ttft_p50_ms": (round(1e3 * float(np.median(
+            [r.first_token_s - r.admitted_s for r in served])), 2)
+            if served else None),
+        "compiles": eng.stats["compiles"] - compiles0,
         "decode_steps": eng.stats["decode_steps"],
         "nonfinite_tokens": eng.stats["nonfinite_tokens"],
         "prefill_calls": eng.stats["prefill_calls"],

@@ -111,26 +111,75 @@ def decoder_specs(cfg: ModelConfig) -> Dict[str, Any]:
 # ------------------------------------------------------------------ layer
 
 
+# Every layer path names its pieces with the same scopes (``attention``,
+# ``ssd``, ``mlp``, ``moe``, ``norm``; ``embed`` and ``lm_head`` around the
+# stack), so a profiler trace can attribute device time to a layer kind.
+# A scope changes op metadata only, never the program.
+
+
+def _mix(cfg: ModelConfig, pl: Dict, x: jax.Array, attn_fn, ssm_fn
+         ) -> Tuple[jax.Array, Dict]:
+    """``x`` plus the layer's sequence mixer, under its scope: attention,
+    the SSD, or both (hybrid, each in its own).  ``attn_fn``/``ssm_fn``
+    return (output, cache).  The residual add sits in the scope, so an
+    output projection fused into it is counted with its mixer."""
+    if cfg.family == "hybrid":
+        with jax.named_scope("attention"):
+            a, ca = attn_fn()
+        with jax.named_scope("ssd"):
+            s, cs = ssm_fn()
+        return (x + 0.5 * (a * pl["alpha_attn"] + s * pl["alpha_ssm"]),
+                {"attn": ca, "ssm": cs})
+    if cfg.has_ssm:
+        with jax.named_scope("ssd"):
+            mix, cs = ssm_fn()
+            return x + mix, {"ssm": cs}
+    with jax.named_scope("attention"):
+        mix, ca = attn_fn()
+        return x + mix, {"attn": ca}
+
+
+def _norm(cfg: ModelConfig, p: Dict, x: jax.Array) -> jax.Array:
+    with jax.named_scope("norm"):
+        return apply_norm(cfg, p, x)
+
+
+def _ffn(cfg: ModelConfig, mesh: Optional[Mesh], use_moe: bool, pl: Dict,
+         x: jax.Array) -> jax.Array:
+    """The layer's second half: ``x`` plus the MoE or MLP of its norm (the
+    residual add in the scope, as in ``_mix``)."""
+    if use_moe and cfg.is_moe:
+        h = _norm(cfg, pl["norm2"], x)
+        with jax.named_scope("moe"):
+            return x + moe_mod.moe_apply(cfg, pl["moe"], h, mesh=mesh)
+    if cfg.d_ff > 0:
+        h = _norm(cfg, pl["norm2"], x)
+        with jax.named_scope("mlp"):
+            return x + mlp_apply(cfg, pl["mlp"], h)
+    return x
+
+
+def _embed(cfg: ModelConfig, params: Dict, tokens: jax.Array) -> jax.Array:
+    with jax.named_scope("embed"):
+        return embed_apply(params, tokens).astype(jnp.dtype(cfg.dtype))
+
+
+def _head(cfg: ModelConfig, params: Dict, x: jax.Array) -> jax.Array:
+    """Final norm, then the output head's logits."""
+    x = _norm(cfg, params["final_norm"], x)
+    with jax.named_scope("lm_head"):
+        return unembed_apply(cfg, params, x)
+
+
 def _layer_train(cfg: ModelConfig, mesh: Optional[Mesh], use_moe: bool,
                  x: jax.Array, pl: Dict) -> jax.Array:
-    dp = dp_axes(mesh)
-    h = apply_norm(cfg, pl["norm1"], x)
-    if cfg.family == "hybrid":
-        a = attn.attn_train(cfg, pl["attn"], h)
-        s = ssm_mod.ssm_train(cfg, pl["ssm"], h)
-        mix = 0.5 * (a * pl["alpha_attn"] + s * pl["alpha_ssm"])
-    elif cfg.has_ssm:
-        mix = ssm_mod.ssm_train(cfg, pl["ssm"], h)
-    else:
-        mix = attn.attn_train(cfg, pl["attn"], h)
-    x = x + mix
+    h = _norm(cfg, pl["norm1"], x)
+    x, _ = _mix(cfg, pl, x,
+                lambda: (attn.attn_train(cfg, pl["attn"], h), None),
+                lambda: (ssm_mod.ssm_train(cfg, pl["ssm"], h), None))
     x = constrain(x, mesh, _residual_spec(cfg, mesh))
-    if use_moe and cfg.is_moe:
-        x = x + moe_mod.moe_apply(cfg, pl["moe"], apply_norm(cfg, pl["norm2"], x),
-                                  mesh=mesh)
-    elif cfg.d_ff > 0:
-        x = x + mlp_apply(cfg, pl["mlp"], apply_norm(cfg, pl["norm2"], x))
-    return constrain(x, mesh, _residual_spec(cfg, mesh))
+    return constrain(_ffn(cfg, mesh, use_moe, pl, x), mesh,
+                     _residual_spec(cfg, mesh))
 
 
 def _scan_stack(cfg: ModelConfig, mesh, use_moe, x, stacked):
@@ -152,14 +201,13 @@ def forward(cfg: ModelConfig, params: Dict, tokens: jax.Array,
             mesh: Optional[Mesh] = None) -> jax.Array:
     """Training forward: tokens (B, S) -> logits (B, S, Vp)."""
     dp = dp_axes(mesh)
-    x = embed_apply(params, tokens).astype(jnp.dtype(cfg.dtype))
+    x = _embed(cfg, params, tokens)
     x = constrain(x, mesh, P(dp if dp else None, None, None))
     if cfg.first_dense_layers > 0:
         dense_cfg = cfg.with_(n_experts=0)
         x = _scan_stack(dense_cfg, mesh, False, x, params["dense_layers"])
     x = _scan_stack(cfg, mesh, cfg.is_moe, x, params["layers"])
-    x = apply_norm(cfg, params["final_norm"], x)
-    logits = unembed_apply(cfg, params, x)
+    logits = _head(cfg, params, x)
     return constrain(logits, mesh, P(dp if dp else None, None, "model"))
 
 
@@ -201,28 +249,12 @@ def init_cache(cfg: ModelConfig, B: int, cache_len: int) -> Dict:
 
 
 def _layer_prefill(cfg, mesh, use_moe, x, pl):
-    dp = dp_axes(mesh)
-    h = apply_norm(cfg, pl["norm1"], x)
-    new_c: Dict[str, Any] = {}
-    if cfg.family == "hybrid":
-        a, ca = attn.attn_prefill(cfg, pl["attn"], h)
-        s, cs = ssm_mod.ssm_prefill(cfg, pl["ssm"], h)
-        mix = 0.5 * (a * pl["alpha_attn"] + s * pl["alpha_ssm"])
-        new_c = {"attn": ca, "ssm": cs}
-    elif cfg.has_ssm:
-        mix, cs = ssm_mod.ssm_prefill(cfg, pl["ssm"], h)
-        new_c = {"ssm": cs}
-    else:
-        mix, ca = attn.attn_prefill(cfg, pl["attn"], h)
-        new_c = {"attn": ca}
-    x = x + mix
+    h = _norm(cfg, pl["norm1"], x)
+    x, new_c = _mix(cfg, pl, x, lambda: attn.attn_prefill(cfg, pl["attn"], h),
+                    lambda: ssm_mod.ssm_prefill(cfg, pl["ssm"], h))
     x = constrain(x, mesh, _residual_spec(cfg, mesh))
-    if use_moe and cfg.is_moe:
-        x = x + moe_mod.moe_apply(cfg, pl["moe"], apply_norm(cfg, pl["norm2"], x),
-                                  mesh=mesh)
-    elif cfg.d_ff > 0:
-        x = x + mlp_apply(cfg, pl["mlp"], apply_norm(cfg, pl["norm2"], x))
-    return constrain(x, mesh, _residual_spec(cfg, mesh)), new_c
+    return (constrain(_ffn(cfg, mesh, use_moe, pl, x), mesh,
+                      _residual_spec(cfg, mesh)), new_c)
 
 
 def _scan_prefill(cfg, mesh, use_moe, x, stacked):
@@ -246,7 +278,7 @@ def prefill(cfg: ModelConfig, params: Dict, tokens: jax.Array,
     """
     B, S = tokens.shape
     dp = dp_axes(mesh)
-    x = embed_apply(params, tokens).astype(jnp.dtype(cfg.dtype))
+    x = _embed(cfg, params, tokens)
     x = constrain(x, mesh, P(dp if dp else None, None, None))
     cache: Dict[str, Any] = {"pos": jnp.full((B,), S, jnp.int32)}
     if cfg.first_dense_layers > 0:
@@ -258,8 +290,7 @@ def prefill(cfg: ModelConfig, params: Dict, tokens: jax.Array,
     else:
         x, cl = _scan_prefill(cfg, mesh, cfg.is_moe, x, params["layers"])
         cache["layers"] = _pad_cache(cfg, cl, S, cache_len)
-    x = apply_norm(cfg, params["final_norm"], x[:, -1:, :])
-    logits = unembed_apply(cfg, params, x)
+    logits = _head(cfg, params, x[:, -1:, :])
     return logits, cache
 
 
@@ -299,26 +330,11 @@ def _pad_cache(cfg: ModelConfig, c: Dict, S: int, cache_len: int) -> Dict:
 
 
 def _layer_decode(cfg, mesh, use_moe, x, pl, cl, pos):
-    h = apply_norm(cfg, pl["norm1"], x)
-    new_c: Dict[str, Any] = {}
-    if cfg.family == "hybrid":
-        a, ca = attn.attn_decode(cfg, pl["attn"], h, cl["attn"], pos)
-        s, cs = ssm_mod.ssm_decode(cfg, pl["ssm"], h, cl["ssm"])
-        mix = 0.5 * (a * pl["alpha_attn"] + s * pl["alpha_ssm"])
-        new_c = {"attn": ca, "ssm": cs}
-    elif cfg.has_ssm:
-        mix, cs = ssm_mod.ssm_decode(cfg, pl["ssm"], h, cl["ssm"])
-        new_c = {"ssm": cs}
-    else:
-        mix, ca = attn.attn_decode(cfg, pl["attn"], h, cl["attn"], pos)
-        new_c = {"attn": ca}
-    x = x + mix
-    if use_moe and cfg.is_moe:
-        x = x + moe_mod.moe_apply(cfg, pl["moe"], apply_norm(cfg, pl["norm2"], x),
-                                  mesh=mesh)
-    elif cfg.d_ff > 0:
-        x = x + mlp_apply(cfg, pl["mlp"], apply_norm(cfg, pl["norm2"], x))
-    return x, new_c
+    h = _norm(cfg, pl["norm1"], x)
+    x, new_c = _mix(
+        cfg, pl, x, lambda: attn.attn_decode(cfg, pl["attn"], h, cl["attn"], pos),
+        lambda: ssm_mod.ssm_decode(cfg, pl["ssm"], h, cl["ssm"]))
+    return _ffn(cfg, mesh, use_moe, pl, x), new_c
 
 
 def decode(cfg: ModelConfig, params: Dict, cache: Dict, tokens: jax.Array,
@@ -333,7 +349,7 @@ def decode(cfg: ModelConfig, params: Dict, cache: Dict, tokens: jax.Array,
     dp = dp_axes(mesh)
     pos = jnp.broadcast_to(jnp.asarray(cache["pos"], jnp.int32),
                            (tokens.shape[0],))
-    x = embed_apply(params, tokens).astype(jnp.dtype(cfg.dtype))
+    x = _embed(cfg, params, tokens)
     x = constrain(x, mesh, P(dp if dp else None, None, None))
     new_cache: Dict[str, Any] = {"pos": pos + 1}
 
@@ -360,7 +376,6 @@ def decode(cfg: ModelConfig, params: Dict, cache: Dict, tokens: jax.Array,
                              (params["layers"], cache["layers"]),
                              unroll=_unroll(cfg, cfg.n_layers))
         new_cache["layers"] = nl
-    x = apply_norm(cfg, params["final_norm"], x)
-    logits = unembed_apply(cfg, params, x)
+    logits = _head(cfg, params, x)
     logits = constrain(logits, mesh, P(dp if dp else None, None, "model"))
     return logits, new_cache

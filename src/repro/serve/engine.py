@@ -17,21 +17,61 @@ engine pre-solves the COMET block-selection plans for its prefill and
 decode kernel shapes through ``PlanCache.warmup``, so the first traced
 kernel finds its plan already on disk instead of running a search inside
 the trace.
+
+**Tracing.** The engine names its work on the profiler's own clock, so a
+trace taken with ``jax.profiler`` puts the host loop beside the device:
+the steps are the programs ``jit_prefill_step`` and ``jit_decode_step``;
+each iteration of the decode loop is a ``serve.step`` step span holding
+``serve.decode`` (token cast, decode launch, argmax and finite-check
+dispatch; arg ``rows`` live), ``serve.sync`` (the wait for the tokens),
+``serve.emit`` (the per-slot token loop), ``serve.admit`` (slot
+assignment; ``rows`` admitted) and, on a refill, ``serve.prefill``
+(``rows`` admitted, ``batch``) and ``serve.splice``.  A span costs one
+call that returns at once when no profiler records.  ``stats`` counts
+``rows_admitted`` and ``compiles`` (executables built while ``run`` is
+active, each function named once in a WARNING), and every ``Request``
+is stamped on ``time.perf_counter``: ``admitted_s``, ``first_token_s``,
+``done_s``.
 """
 from __future__ import annotations
 
+import logging
+import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Set
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import Mesh
 
 from ..models.model import Model
 
 __all__ = ["Request", "ServeEngine"]
+
+log = logging.getLogger(__name__)
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_serving = threading.local()        # .engine: the engine in run() on this thread
+_warned: Set[str] = set()
+
+
+def _count_compile(event: str, duration_s: float, **kw) -> None:
+    """Count an executable built while an engine serves (a compile inside
+    ``run`` stalls every slot), naming its function once per process."""
+    eng = getattr(_serving, "engine", None)
+    if event != COMPILE_EVENT or eng is None:
+        return
+    eng.stats["compiles"] += 1
+    name = kw.get("fun_name", "?")
+    if name not in _warned:
+        _warned.add(name)
+        log.warning("%s compiled while serving", name)
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
 
 
 @dataclass
@@ -48,6 +88,9 @@ class Request:
     output: List[int] = field(default_factory=list)
     done: bool = False
     timed_out: bool = False
+    admitted_s: Optional[float] = None      # time.perf_counter() stamps
+    first_token_s: Optional[float] = None
+    done_s: Optional[float] = None
 
 
 class ServeEngine:
@@ -76,18 +119,23 @@ class ServeEngine:
         cfg = model.cfg
 
         # the engine's two compiled steps (public so a caller can lower
-        # and inspect the exact programs the engine runs)
-        self.prefill_step = jax.jit(
-            lambda p, b: model.prefill(p, b, cache_len, mesh))
-        self.decode_step = jax.jit(
-            lambda p, c, t: model.decode(p, c, t, mesh),
-            donate_argnums=(1,))
+        # and inspect the exact programs the engine runs); their names
+        # name the device programs, jit_prefill_step and jit_decode_step
+        def prefill_step(p, b):
+            return model.prefill(p, b, cache_len, mesh)
+
+        def decode_step(p, c, t):
+            return model.decode(p, c, t, mesh)
+
+        self.prefill_step = jax.jit(prefill_step)
+        self.decode_step = jax.jit(decode_step, donate_argnums=(1,))
         # nonfinite_tokens: tokens taken (by argmax) from a logits row
         # that held a NaN or an inf; argmax still yields an in-vocabulary
         # id there, so only this count shows a broken decode step
         self.stats: Dict[str, float] = {"prefill_calls": 0, "decode_steps": 0,
                                         "tokens_out": 0, "timeouts": 0,
-                                        "nonfinite_tokens": 0}
+                                        "nonfinite_tokens": 0,
+                                        "rows_admitted": 0, "compiles": 0}
         if plan_warmup:
             self.warm_plans()
 
@@ -168,24 +216,26 @@ class ServeEngine:
         freed this step) and splice their rows — KV/state cache, position,
         last token — into the live decode state."""
         rows = [r if i in idxs else None for i, r in enumerate(active)]
-        fresh_last, fresh = self._prefill_batch(rows)
+        with TraceAnnotation("serve.prefill", rows=len(idxs), batch=self.B):
+            fresh_last, fresh = self._prefill_batch(rows)
         if cache is None:                  # initial fill: take it wholesale
             return fresh_last, fresh
-        sel = np.zeros(self.B, dtype=bool)
-        sel[idxs] = True
-        selj = jnp.asarray(sel)
+        with TraceAnnotation("serve.splice"):
+            sel = np.zeros(self.B, dtype=bool)
+            sel[idxs] = True
+            selj = jnp.asarray(sel)
 
-        def splice(old, new):
-            # stacked cache leaves are (L, B, ...): batch axis 1
-            shape = [1] * old.ndim
-            shape[1] = self.B
-            return jnp.where(selj.reshape(shape), new, old)
+            def splice(old, new):
+                # stacked cache leaves are (L, B, ...): batch axis 1
+                shape = [1] * old.ndim
+                shape[1] = self.B
+                return jnp.where(selj.reshape(shape), new, old)
 
-        merged = {"pos": jnp.where(selj, fresh["pos"], cache["pos"])}
-        for key in cache:
-            if key != "pos":
-                merged[key] = jax.tree.map(splice, cache[key], fresh[key])
-        return jnp.where(selj, fresh_last, last), merged
+            merged = {"pos": jnp.where(selj, fresh["pos"], cache["pos"])}
+            for key in cache:
+                if key != "pos":
+                    merged[key] = jax.tree.map(splice, cache[key], fresh[key])
+            return jnp.where(selj, fresh_last, last), merged
 
     def _token_budget(self, r: Request) -> int:
         return (r.max_new_tokens if self.max_new_cap is None
@@ -194,52 +244,73 @@ class ServeEngine:
     def run(self, requests: List[Request], *, max_steps: int = 10_000
             ) -> List[Request]:
         """Process all requests with continuous slot reuse."""
+        prev, _serving.engine = getattr(_serving, "engine", None), self
+        try:
+            self._run(requests, max_steps)
+        finally:
+            _serving.engine = prev
+        return list(requests)
+
+    def _run(self, requests: List[Request], max_steps: int) -> None:
         queue = list(requests)
         active: List[Optional[Request]] = [None] * self.B
-        admitted: List[float] = [0.0] * self.B    # slot admission times
 
         def refill() -> List[int]:
-            new = []
-            for i in range(self.B):
-                if active[i] is None and queue:
-                    active[i] = queue.pop(0)
-                    admitted[i] = time.monotonic()
-                    new.append(i)
-            return new
+            free = [i for i, r in enumerate(active) if r is None][:len(queue)]
+            with TraceAnnotation("serve.admit", rows=len(free)):
+                now = time.perf_counter()
+                for i in free:
+                    r = active[i] = queue.pop(0)
+                    r.admitted_s = now
+                self.stats["rows_admitted"] += len(free)
+            return free
 
         last, cache = self._refill_prefill(active, refill(), None, None)
 
         for _step in range(max_steps):
-            if all(r is None or r.done for r in active) and not queue:
+            live = sum(r is not None and not r.done for r in active)
+            if not live and not queue:
                 break
-            tok = last[:, None].astype(jnp.int32)
-            logits, cache = self.decode_step(self.params, cache, tok)
-            self.stats["decode_steps"] += 1
-            step = logits[:, -1, :self.model.cfg.vocab_size]
-            last = jnp.argmax(step, -1)
-            host, finite = jax.device_get((last, jnp.isfinite(step).all(-1)))
-            now = time.monotonic()
-            for i, r in enumerate(active):
-                if r is None or r.done:
-                    continue
-                r.output.append(int(host[i]))
-                self.stats["tokens_out"] += 1
-                self.stats["nonfinite_tokens"] += int(not finite[i])
-                deadline = (r.deadline_s if r.deadline_s is not None
-                            else self.default_deadline_s)
-                if deadline is not None and now - admitted[i] >= deadline:
-                    # runaway guard: force-finish instead of pinning the
-                    # slot until the engine-global max_steps
-                    r.timed_out = True
-                    self.stats["timeouts"] += 1
-                elif not (len(r.output) >= self._token_budget(r)
-                          or (r.eos_id is not None and host[i] == r.eos_id)):
-                    continue
-                r.done = True
-                active[i] = None           # slot freed (continuous batching)
-            new = refill()
-            if new:
-                # the bug this fixes: refilled slots used to inherit the
-                # previous occupant's KV cache and last token
-                last, cache = self._refill_prefill(active, new, cache, last)
-        return [r for r in requests]
+            with StepTraceAnnotation("serve.step",
+                                     step_num=self.stats["decode_steps"]):
+                with TraceAnnotation("serve.decode", rows=live):
+                    tok = last[:, None].astype(jnp.int32)
+                    logits, cache = self.decode_step(self.params, cache, tok)
+                    self.stats["decode_steps"] += 1
+                    step = logits[:, -1, :self.model.cfg.vocab_size]
+                    last = jnp.argmax(step, -1)
+                    finite = jnp.isfinite(step).all(-1)
+                with TraceAnnotation("serve.sync"):
+                    host, finite = jax.device_get((last, finite))
+                with TraceAnnotation("serve.emit"):
+                    self._emit(active, host, finite)
+                new = refill()
+                if new:
+                    # the bug this fixes: refilled slots used to inherit the
+                    # previous occupant's KV cache and last token
+                    last, cache = self._refill_prefill(active, new, cache, last)
+
+    def _emit(self, active: List[Optional[Request]], host, finite) -> None:
+        """Append each live slot's token; finish (and free) the slots whose
+        request reached its budget, its eos or its deadline."""
+        now = time.perf_counter()
+        for i, r in enumerate(active):
+            if r is None or r.done:
+                continue
+            r.output.append(int(host[i]))
+            if r.first_token_s is None:
+                r.first_token_s = now
+            self.stats["tokens_out"] += 1
+            self.stats["nonfinite_tokens"] += int(not finite[i])
+            deadline = (r.deadline_s if r.deadline_s is not None
+                        else self.default_deadline_s)
+            if deadline is not None and now - r.admitted_s >= deadline:
+                # runaway guard: force-finish instead of pinning the
+                # slot until the engine-global max_steps
+                r.timed_out = True
+                self.stats["timeouts"] += 1
+            elif not (len(r.output) >= self._token_budget(r)
+                      or (r.eos_id is not None and host[i] == r.eos_id)):
+                continue
+            r.done, r.done_s = True, now
+            active[i] = None               # slot freed (continuous batching)
