@@ -82,6 +82,7 @@ from __future__ import annotations
 
 import inspect
 import math
+import multiprocessing
 import os
 import random
 import secrets
@@ -607,6 +608,17 @@ def _run_search_job(job) -> SearchResult:
     return search(co, arch, **kw)
 
 
+def _process_pool(max_workers: Optional[int]) -> ProcessPoolExecutor:
+    """A process pool whose workers fork from a fork server rather than
+    from this process: this one may run threads (JAX's, a caller's), and
+    a worker forked while one of them holds a lock can wait on it
+    forever.  The server imports this module once, so each worker starts
+    with it loaded."""
+    ctx = multiprocessing.get_context("forkserver")
+    ctx.set_forkserver_preload([__name__])
+    return ProcessPoolExecutor(max_workers=max_workers, mp_context=ctx)
+
+
 def parallel_map(fn: Callable, items: Sequence, *,
                  max_workers: Optional[int] = None,
                  executor: str = "auto") -> List:
@@ -626,9 +638,9 @@ def parallel_map(fn: Callable, items: Sequence, *,
     items = list(items)
     if executor == "serial" or len(items) <= 1:
         return [fn(it) for it in items]
-    pool_cls = ProcessPoolExecutor if executor == "process" else ThreadPoolExecutor
     try:
-        pool = pool_cls(max_workers=max_workers)
+        pool = (_process_pool(max_workers) if executor == "process"
+                else ThreadPoolExecutor(max_workers=max_workers))
     except (OSError, PermissionError, ImportError) as e:
         # Pool creation failed (e.g. sandbox without multiprocessing
         # primitives) — errors raised by fn itself still propagate below.
@@ -873,7 +885,7 @@ def _search_many_process(jobs: List[Tuple[CompoundOp, Arch, Dict]], *,
         chunksize = max(1, math.ceil(len(jobs) / (workers * 4)))
     chunks = _make_chunks(jobs, chunksize, chunking)
     try:
-        pool = ProcessPoolExecutor(max_workers=max_workers)
+        pool = _process_pool(max_workers)
     except (OSError, PermissionError, ImportError) as e:
         warnings.warn(
             f"search_many: process pool unavailable ({e!r}); falling back "

@@ -6,11 +6,20 @@ changes).
 Continuous batching is *correct* continuous batching: when a slot frees
 mid-decode, the request that takes it over is **re-prefilled** — all slots
 refilled in the same step share one batched prefill call — and its rows of
-the KV cache, per-slot position vector and last-token vector are spliced
-in while the other slots keep decoding undisturbed.  (The per-slot
-positions come from the model layer: ``cache['pos']`` is a (B,) vector and
-attention masks/RoPE are per-row, so a freshly prefilled slot decodes
-exactly as it would in a batch of its own.)
+the KV cache, per-slot position vector and last-token vector are written
+into the live decode state while the other slots keep decoding
+undisturbed.  (The per-slot positions come from the model layer:
+``cache['pos']`` is a (B,) vector and attention masks/RoPE are per-row, so
+a freshly prefilled slot decodes exactly as it would in a batch of its
+own.)  A refill of ``k < B`` slots prefills only those rows, padded with
+zero rows to the smallest *row bucket* that holds them (1, 4, 16, 64, …
+up to B, with B itself; under a mesh rounded up to a multiple of the
+data-parallel size), and one jitted splice (``splice_rows``, donated)
+scatters them into the live state in place, so only that state and the
+bucket's rows are alive at a refill.  The first fill, and a refill of all
+B slots, prefill B rows and take them whole.  Each bucket's prefill and
+splice are compiled when the engine is built, so serving compiles
+nothing new however many slots a step frees.
 
 Startup also **warms the mapping-plan cache** (`repro.core.plan`): the
 engine pre-solves the COMET block-selection plans for its prefill and
@@ -26,12 +35,14 @@ each iteration of the decode loop is a ``serve.step`` step span holding
 dispatch; arg ``rows`` live), ``serve.sync`` (the wait for the tokens),
 ``serve.emit`` (the per-slot token loop), ``serve.admit`` (slot
 assignment; ``rows`` admitted) and, on a refill, ``serve.prefill``
-(``rows`` admitted, ``batch``) and ``serve.splice``.  A span costs one
-call that returns at once when no profiler records.  ``stats`` counts
-``rows_admitted`` and ``compiles`` (executables built while ``run`` is
-active, each function named once in a WARNING), and every ``Request``
-is stamped on ``time.perf_counter``: ``admitted_s``, ``first_token_s``,
-``done_s``.
+(``rows`` admitted, ``batch`` the rows prefilled: the bucket) and
+``serve.splice``.  A span costs one call that returns at once when no
+profiler records.  ``stats`` counts ``rows_admitted``, ``rows_prefilled``
+(the rows of every prefill call; ``rows_admitted / rows_prefilled`` is
+the share of prefilled rows a request used) and ``compiles`` (executables
+built while ``run`` is active, each function named once in a WARNING),
+and every ``Request`` is stamped on ``time.perf_counter``:
+``admitted_s``, ``first_token_s``, ``done_s``.
 """
 from __future__ import annotations
 
@@ -48,8 +59,9 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import Mesh
 
 from ..models.model import Model
+from ..parallel.sharding import dp_size
 
-__all__ = ["Request", "ServeEngine"]
+__all__ = ["Request", "ServeEngine", "row_buckets"]
 
 log = logging.getLogger(__name__)
 
@@ -72,6 +84,17 @@ def _count_compile(event: str, duration_s: float, **kw) -> None:
 
 
 jax.monitoring.register_event_duration_secs_listener(_count_compile)
+
+
+def row_buckets(batch: int, multiple: int = 1) -> List[int]:
+    """The row counts a refill prefills at: 1, 4, 16, 64, … below
+    ``batch``, each rounded up to a multiple of ``multiple`` (the
+    data-parallel size that shards the batch), then ``batch`` itself."""
+    out, n = set(), 1
+    while n < batch:
+        out.add(-(-n // multiple) * multiple)
+        n *= 4
+    return sorted(b for b in out if b < batch) + [batch]
 
 
 @dataclass
@@ -117,27 +140,47 @@ class ServeEngine:
         self.max_new_cap = max_new_cap
         self.default_deadline_s = default_deadline_s
         cfg = model.cfg
-
-        # the engine's two compiled steps (public so a caller can lower
-        # and inspect the exact programs the engine runs); their names
-        # name the device programs, jit_prefill_step and jit_decode_step
+        # the engine's compiled steps (public so a caller can lower and
+        # inspect the exact programs the engine runs); their names name the
+        # device programs, jit_prefill_step, jit_decode_step, jit_splice_rows
         def prefill_step(p, b):
             return model.prefill(p, b, cache_len, mesh)
 
         def decode_step(p, c, t):
             return model.decode(p, c, t, mesh)
 
+        def splice_rows(cache, last, logits, fresh, idx):
+            # the fresh rows' first tokens, then each row written at its
+            # slot: batch axis 0 of pos and last, axis 1 of every stacked
+            # leaf; an index of B (a padding row) writes nothing
+            first = jnp.argmax(logits[:, -1, :cfg.vocab_size], -1)
+            out = {"pos": cache["pos"].at[idx].set(fresh["pos"], mode="drop")}
+            for key in cache:
+                if key != "pos":
+                    out[key] = jax.tree.map(
+                        lambda old, new: old.at[:, idx].set(new, mode="drop"),
+                        cache[key], fresh[key])
+            return last.at[idx].set(first.astype(last.dtype), mode="drop"), out
+
         self.prefill_step = jax.jit(prefill_step)
         self.decode_step = jax.jit(decode_step, donate_argnums=(1,))
+        self.splice_rows = jax.jit(splice_rows, donate_argnums=(0, 1))
+        self.buckets = row_buckets(
+            batch_size, dp_size(mesh) if mesh is not None else 1)
         # nonfinite_tokens: tokens taken (by argmax) from a logits row
         # that held a NaN or an inf; argmax still yields an in-vocabulary
         # id there, so only this count shows a broken decode step
         self.stats: Dict[str, float] = {"prefill_calls": 0, "decode_steps": 0,
                                         "tokens_out": 0, "timeouts": 0,
                                         "nonfinite_tokens": 0,
-                                        "rows_admitted": 0, "compiles": 0}
+                                        "rows_admitted": 0,
+                                        "rows_prefilled": 0, "compiles": 0}
         if plan_warmup:
             self.warm_plans()
+        # weights given as shapes (to lower the steps for another device)
+        # cannot serve, so there is nothing to warm
+        if all(isinstance(x, jax.Array) for x in jax.tree.leaves(params)):
+            self.warm_refills()
 
     # ------------------------------------------------------------- plans
     def plan_shapes(self) -> Dict[str, List]:
@@ -180,62 +223,77 @@ class ServeEngine:
         self.stats["plan_warmup_s"] = time.time() - t0
         return stats
 
-    # ------------------------------------------------------------- serving
-    def _pad_prompts(self, rows: Sequence[Optional[Request]]) -> np.ndarray:
-        """(B, prompt_len) token rows, right-aligned; ``None`` rows (empty
-        or not-being-refilled slots) stay zero."""
-        toks = np.zeros((self.B, self.prompt_len), np.int32)
-        for i, r in enumerate(rows):
-            if r is None:
-                continue
-            t = r.prompt[-self.prompt_len:]
-            toks[i, -len(t):] = t          # right-aligned
-        return toks
+    def warm_refills(self) -> None:
+        """Compile, ahead of time, the prefill of every row bucket below B
+        and the splice of every bucket into the live B-row state, so a
+        refill of any size builds nothing while serving.  (A compile ahead
+        of time fills ``jax.jit``'s cache for arguments of the same shapes
+        and placement.)  The B-row prefill and the decode step compile at
+        the first fill, as they always have.  Under a mesh the live state
+        is placed by the decode step, so there the splice compiles again
+        at the first partial refill."""
+        _, live = jax.eval_shape(self.prefill_step, self.params,
+                                 self._batch([None] * self.B))
+        last = jax.ShapeDtypeStruct((self.B,), jnp.int32)
+        for n in self.buckets:
+            batch = self._batch([None] * n)
+            if n < self.B:
+                self.prefill_step.lower(self.params, batch).compile()
+            logits, fresh = jax.eval_shape(self.prefill_step, self.params,
+                                           batch)
+            idx = jax.ShapeDtypeStruct((n,), jnp.int32)
+            self.splice_rows.lower(live, last, logits, fresh, idx).compile()
 
-    def _prefill_batch(self, rows: Sequence[Optional[Request]]):
-        """One batched prefill over ``rows`` (None rows carry zeros).
-        Returns (last-token vector, cache with per-slot positions)."""
-        batch = {"tokens": jnp.asarray(self._pad_prompts(rows))}
-        if self.model.cfg.is_encdec:
-            Se = max(1, self.prompt_len // self.model.cfg.enc_ratio)
-            batch["src_embeds"] = jnp.zeros(
-                (self.B, Se, self.model.cfg.d_model), jnp.float32)
-        logits, cache = self.prefill_step(self.params, batch)
-        self.stats["prefill_calls"] += 1
-        last = jnp.argmax(logits[:, -1, :self.model.cfg.vocab_size], -1)
-        cache = dict(cache)
-        # per-slot decode positions from the start: the merged cache keeps
-        # one compiled decode shape whether or not slots ever diverge
-        cache["pos"] = jnp.broadcast_to(
-            jnp.asarray(cache["pos"], jnp.int32), (self.B,))
-        return last, cache
+    # ------------------------------------------------------------- serving
+    def _batch(self, rows: Sequence[Optional[Request]]
+               ) -> Dict[str, jax.Array]:
+        """The prefill batch for ``rows`` (``None``: a zero row): their
+        prompts right-aligned in (rows, prompt_len) tokens, and for an
+        encoder-decoder zero source embeddings."""
+        toks = np.zeros((len(rows), self.prompt_len), np.int32)
+        for i, r in enumerate(rows):
+            if r is not None:
+                t = r.prompt[-self.prompt_len:]
+                toks[i, -len(t):] = t          # right-aligned
+        batch = {"tokens": jnp.asarray(toks)}
+        cfg = self.model.cfg
+        if cfg.is_encdec:
+            Se = max(1, self.prompt_len // cfg.enc_ratio)
+            batch["src_embeds"] = jnp.asarray(
+                np.zeros((len(rows), Se, cfg.d_model), np.float32))
+        return batch
+
+    def bucket(self, k: int) -> int:
+        """Rows a refill of ``k`` slots prefills: the least bucket >= k."""
+        return next(b for b in self.buckets if b >= k)
 
     def _refill_prefill(self, active: Sequence[Optional[Request]],
                         idxs: List[int], cache, last):
-        """Prefill the newly refilled slots (one batched call however many
-        freed this step) and splice their rows — KV/state cache, position,
-        last token — into the live decode state."""
-        rows = [r if i in idxs else None for i, r in enumerate(active)]
-        with TraceAnnotation("serve.prefill", rows=len(idxs), batch=self.B):
-            fresh_last, fresh = self._prefill_batch(rows)
-        if cache is None:                  # initial fill: take it wholesale
-            return fresh_last, fresh
+        """Prefill the newly refilled slots ``idxs`` (one batched call
+        however many freed this step) and write their rows — KV/state
+        cache, position, last token — into the live decode state.  The
+        first fill and a refill of every slot prefill B rows in slot order
+        and take them whole; a refill of fewer prefills ``bucket(k)``
+        rows, zero rows first and the admitted ones last (so a fault that
+        drops a call's back rows lands on served rows, not on padding),
+        and splices them in place."""
+        k = len(idxs)
+        whole = cache is None or k == self.B
+        n = self.B if whole else self.bucket(k)
+        rows = ([r if i in idxs else None for i, r in enumerate(active)]
+                if whole else [None] * (n - k) + [active[i] for i in idxs])
+        with TraceAnnotation("serve.prefill", rows=k, batch=n):
+            logits, fresh = self.prefill_step(self.params, self._batch(rows))
+            self.stats["prefill_calls"] += 1
+            self.stats["rows_prefilled"] += n
+            if whole:
+                return (jnp.argmax(logits[:, -1, :self.model.cfg.vocab_size],
+                                   -1), fresh)
         with TraceAnnotation("serve.splice"):
-            sel = np.zeros(self.B, dtype=bool)
-            sel[idxs] = True
-            selj = jnp.asarray(sel)
-
-            def splice(old, new):
-                # stacked cache leaves are (L, B, ...): batch axis 1
-                shape = [1] * old.ndim
-                shape[1] = self.B
-                return jnp.where(selj.reshape(shape), new, old)
-
-            merged = {"pos": jnp.where(selj, fresh["pos"], cache["pos"])}
-            for key in cache:
-                if key != "pos":
-                    merged[key] = jax.tree.map(splice, cache[key], fresh[key])
-            return jnp.where(selj, fresh_last, last), merged
+            idx = np.full(n, self.B, np.int32)     # padding rows: dropped
+            idx[n - k:] = idxs
+            return self.splice_rows(cache, last, logits, fresh,
+                                    jnp.asarray(idx))
 
     def _token_budget(self, r: Request) -> int:
         return (r.max_new_tokens if self.max_new_cap is None

@@ -466,7 +466,7 @@ def test_parallel_map_broken_pool_falls_back_serial(monkeypatch):
     from repro.core import search as search_mod
 
     class _BreaksAfterOne:
-        def __init__(self, max_workers=None):
+        def __init__(self, max_workers=None, **kw):
             pass
 
         def __enter__(self):

@@ -89,20 +89,25 @@ def test_each_decode_step_is_a_step_span_of_decode_sync_emit(traced):
 
 
 def test_a_refill_admits_prefills_and_splices_with_its_rows(traced):
-    _, reqs, spans = traced
+    eng, reqs, spans = traced
     by_name = defaultdict(list)
     for s in spans:
         by_name[s[0]].append(s)
     admits = [s for s in by_name["serve.admit"] if int(s[3]["rows"]) > 0]
     prefills = by_name["serve.prefill"]
-    # the first fill has no splice; every later refill has one, in order
-    assert len(admits) == len(prefills) == len(by_name["serve.splice"]) + 1
+    # the first fill and a refill of every slot take B fresh rows whole;
+    # every other refill prefills its bucket and has a splice, in order
+    partial = [p for p in prefills[1:] if int(p[3]["rows"]) < B]
+    assert len(admits) == len(prefills)
+    assert 0 < len(partial) == len(by_name["serve.splice"])
     assert sum(int(s[3]["rows"]) for s in admits) == len(reqs)
-    for admit, prefill in zip(admits, prefills):
+    for i, (admit, prefill) in enumerate(zip(admits, prefills)):
         assert admit[2] <= prefill[1]
-        assert int(prefill[3]["rows"]) == int(admit[3]["rows"])
-        assert int(prefill[3]["batch"]) == B
-    for prefill, splice in zip(prefills[1:], by_name["serve.splice"]):
+        rows = int(prefill[3]["rows"])
+        assert rows == int(admit[3]["rows"])
+        assert int(prefill[3]["batch"]) == (
+            B if i == 0 or rows == B else eng.bucket(rows))
+    for prefill, splice in zip(partial, by_name["serve.splice"]):
         assert prefill[2] <= splice[1]
 
 

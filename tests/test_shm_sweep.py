@@ -203,7 +203,7 @@ def test_worker_crash_reclaims_orphans_and_finishes_serially(monkeypatch):
     orphan_name = f"{prefix}_orphan"
 
     class _CrashingPool:
-        def __init__(self, max_workers=None):
+        def __init__(self, max_workers=None, **kw):
             pass
 
         def __enter__(self):
@@ -259,7 +259,7 @@ def test_worker_killed_mid_shm_write_torn_segment_reclaimed(monkeypatch):
     torn_name = f"{prefix}_torn0001"
 
     class _KilledMidWritePool:
-        def __init__(self, max_workers=None):
+        def __init__(self, max_workers=None, **kw):
             pass
 
         def __enter__(self):
@@ -348,7 +348,7 @@ def test_cleanup_races_concurrent_healthy_sweep():
 
 def test_pool_unavailable_falls_back_to_threads_with_warning(monkeypatch):
     class _NoPool:
-        def __init__(self, max_workers=None):
+        def __init__(self, max_workers=None, **kw):
             raise OSError("no process pools here")
 
     monkeypatch.setattr(search_mod, "ProcessPoolExecutor", _NoPool)
@@ -361,7 +361,7 @@ def test_pool_unavailable_falls_back_to_threads_with_warning(monkeypatch):
 
 def test_parallel_map_pool_creation_failure_warns_and_runs_serial(monkeypatch):
     class _NoPool:
-        def __init__(self, max_workers=None):
+        def __init__(self, max_workers=None, **kw):
             raise OSError("no threads either")
 
     monkeypatch.setattr(search_mod, "ThreadPoolExecutor", _NoPool)

@@ -166,6 +166,11 @@ def _engine(cfg, params):
                        plan_warmup=False)
 
 
+def _on(sharding, tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=sharding), tree)
+
+
 def _fits_one_chip(compiled):
     ma = compiled.memory_analysis()
     return (ma.argument_size_in_bytes + ma.output_size_in_bytes
@@ -195,3 +200,46 @@ def test_serve_decode_compiles(serve_shapes, one_chip):
         s.shape, s.dtype, sharding=one_chip), cache)
     tok = jax.ShapeDtypeStruct((SERVE_BATCH, 1), jnp.int32, sharding=one_chip)
     assert _fits_one_chip(eng.decode_step.lower(params, cache, tok).compile())
+
+
+# the mamba2.chat benchmark cell: 128 slots of 256-token prompts; a refill
+# of a few slots prefills the 4-row bucket
+REFILL_ARCH, REFILL_BATCH, REFILL_PROMPT, REFILL_CACHE, REFILL_ROWS = (
+    "mamba2-130m", 128, 256, 1280, 4)
+
+
+def test_refill_prefills_a_bucket_and_splices_in_place(one_chip):
+    """At published widths, the 4-row refill prefill fits one chip, and
+    the splice that writes its rows into the 128-slot state (2.4 GB of
+    f32 SSD state) aliases its output to the donated state: no second
+    copy of the state is allocated at a refill."""
+    from repro.configs.registry import get_config
+    from repro.models.model import Model
+    from repro.serve.engine import ServeEngine
+
+    cfg = get_config(REFILL_ARCH)
+    params = _on(one_chip, Model(cfg).abstract_params())
+    eng = ServeEngine(Model(cfg), params, batch_size=REFILL_BATCH,
+                      cache_len=REFILL_CACHE, prompt_len=REFILL_PROMPT,
+                      plan_warmup=False)
+    assert eng.bucket(2) == REFILL_ROWS
+
+    def tokens(n):
+        return {"tokens": jax.ShapeDtypeStruct((n, REFILL_PROMPT), jnp.int32,
+                                               sharding=one_chip)}
+
+    bucket = tokens(REFILL_ROWS)
+    assert _fits_one_chip(eng.prefill_step.lower(params, bucket).compile())
+    logits, fresh = _on(one_chip, jax.eval_shape(eng.prefill_step, params,
+                                                 bucket))
+    _, live = _on(one_chip, jax.eval_shape(eng.prefill_step, params,
+                                           tokens(REFILL_BATCH)))
+    last = jax.ShapeDtypeStruct((REFILL_BATCH,), jnp.int32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((REFILL_ROWS,), jnp.int32, sharding=one_chip)
+    ma = eng.splice_rows.lower(live, last, logits, fresh,
+                               idx).compile().memory_analysis()
+    state = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(live))
+    assert state > 2 * 10**9
+    assert ma.alias_size_in_bytes >= state + REFILL_BATCH * 4
+    assert ma.output_size_in_bytes - ma.alias_size_in_bytes < state // 100
+    assert ma.temp_size_in_bytes < state // 100
