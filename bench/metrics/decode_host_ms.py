@@ -1,7 +1,7 @@
-"""Mean, over decode steps with no refill between, of the time from the
-end of the engine's ``serve.sync`` span (tokens on the host) to the start
-of the next ``jit_decode_step`` execution on the device: the device idle
-the host loop costs each step."""
+"""Mean, over decode steps with no refill between, of the device's idle
+from the end of one step's last program (the decode, its argmax and
+finite check) to the start of the next ``jit_decode_step``, on the
+device's clock: the device idle the host loop costs each step."""
 from .. import serve_trace
 
 

@@ -1,10 +1,10 @@
-"""Requests admitted over the rows the engine's prefill calls computed
-(``stats["prefill_calls"]`` times the batch), in the window."""
+"""Rows admitted over the rows the engine's prefill calls computed
+(``stats["rows_admitted"]`` over ``stats["rows_prefilled"]``), in the
+window: the share of prefilled rows that held a request."""
 
 
 def read(run):
-    calls = run.counters.get("prefill_calls", 0)
-    if not calls:
+    prefilled = run.counters.get("rows_prefilled", 0)
+    if not prefilled:
         return None
-    admitted = sum(l.rows for l in run.launches if l.kind == "prefill")
-    return 100.0 * admitted / (calls * run.mix.batch)
+    return 100.0 * run.counters["rows_admitted"] / prefilled

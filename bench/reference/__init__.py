@@ -1,14 +1,22 @@
-"""Plain forwards that decide ``correct``: one module per model family,
-named by the ``reference`` key of a configuration file.
+"""Everything the benchmark knows of a model family: one module per
+family, named by the ``reference`` key of a configuration file.  A new
+family is one new module here; no other file of the benchmark names one.
 
-A family module gives ``hidden(cfg, params, tokens, mm)``: the final,
-normed hidden state (T, d) in float32 of one sequence, computed from the
-configuration file's published keys and the weight arrays alone, with no
-cache, batching or kernel, and every matrix product through ``mm``; and
-``head(cfg, params)``: the (d, padded vocab) output matrix.  The two
-products below are the reference's (float32 at ``highest``) and the
-control's (int8 weights and activations, the step below the bfloat16 the
-configurations state).
+A family module gives, each from the configuration file's published keys:
+
+- ``hidden(cfg, params, tokens, mm)``: the final, normed hidden state
+  (T, d) in float32 of one sequence, from the weight arrays alone, with no
+  cache, batching or kernel, and every matrix product through ``mm``;
+- ``head(cfg, params)``: the (d, padded vocab) output matrix;
+- ``decode_work(cfg, rows, ctx_sum)`` and ``prefill_work(cfg, rows,
+  prompt_len)``: the ``work.Work`` a decode step and a prefill call need,
+  counted from shapes alone (``bench/work.py`` says what "needed" means);
+- ``program_fields(conf)``: the ``ModelConfig`` fields the file's
+  published keys set, with the values they set them to.
+
+The two products below are the reference's (float32 at ``highest``) and
+the control's (int8 weights and activations, the step below the bfloat16
+the configurations state).
 """
 from __future__ import annotations
 
@@ -45,6 +53,18 @@ def mm_int8(x: jax.Array, w: jax.Array) -> jax.Array:
 def family(name: str):
     """The reference module of a configuration's ``reference`` key."""
     return importlib.import_module(f"{__name__}.{name}")
+
+
+def part(name: str, fn: str):
+    """Function ``fn`` of family ``name``'s module; an error that names
+    both where the module lacks it."""
+    mod = family(name)
+    got = getattr(mod, fn, None)
+    if not callable(got):
+        raise NotImplementedError(
+            f"family module {mod.__name__} gives no {fn}(): a family module "
+            f"gives hidden, head, decode_work, prefill_work and program_fields")
+    return got
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:

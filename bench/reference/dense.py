@@ -7,6 +7,9 @@ Keys read from the configuration file: ``hidden_size``,
 ``rope_theta``, ``rms_norm_eps``, ``tie_word_embeddings``.  The file's
 ``departures`` say where this follows the program rather than the
 published model (full-width RoPE, no LongRoPE, untied output).
+
+Work is counted with the KV cache in bfloat16 and causal attention over
+the lower triangle of the scores (``decode_work``, ``prefill_work``).
 """
 from __future__ import annotations
 
@@ -17,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from . import F32, rms_norm
+from ..work import BF16, Work
 
 
 def _rope(x: jax.Array, theta: float) -> jax.Array:
@@ -72,3 +76,46 @@ def head(cfg: dict, params) -> jax.Array:
     if cfg["tie_word_embeddings"]:
         return params["embedding"].T
     return params["unembed"]
+
+
+def _sizes(cfg: dict):
+    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
+    H, Hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    hd = d // H
+    ff, V = cfg["intermediate_size"], cfg["vocab_size"]
+    mat = L * (2 * d * H * hd + 2 * d * Hkv * hd + 3 * d * ff)
+    norms = L * 2 * d + d
+    kv_token = L * 2 * Hkv * hd * BF16            # cache bytes of one position
+    return d, L, H, hd, V, mat, norms, kv_token
+
+
+def decode_work(cfg: dict, rows: int, ctx_sum: int) -> Work:
+    d, L, H, hd, V, mat, norms, kv_token = _sizes(cfg)
+    keys = ctx_sum + rows                       # the new key is attended
+    flops = rows * 2 * (mat + d * V) + L * 4 * H * hd * keys
+    weights = (mat + norms + d * V) * BF16
+    nbytes = (weights + rows * d * BF16 + kv_token * ctx_sum
+              + kv_token * rows)
+    return Work(flops, nbytes)
+
+
+def prefill_work(cfg: dict, rows: int, prompt_len: int) -> Work:
+    S = prompt_len
+    d, L, H, hd, V, mat, norms, kv_token = _sizes(cfg)
+    flops = rows * (2 * mat * S + L * 4 * H * hd * S * (S + 1) // 2
+                    + 2 * d * V)
+    weights = (mat + norms + d * V) * BF16
+    nbytes = weights + rows * S * (d * BF16 + kv_token)
+    return Work(flops, nbytes)
+
+
+def program_fields(conf: dict) -> dict:
+    return {"d_model": conf["hidden_size"],
+            "n_layers": conf["num_hidden_layers"],
+            "n_heads": conf["num_attention_heads"],
+            "n_kv_heads": conf["num_key_value_heads"],
+            "d_ff": conf["intermediate_size"],
+            "vocab_size": conf["vocab_size"],
+            "rope_theta": conf["rope_theta"],
+            "norm_eps": conf["rms_norm_eps"],
+            "tie_embeddings": conf["tie_word_embeddings"]}

@@ -8,6 +8,11 @@ out-projection, final RMSNorm and the tied output matrix.
 Keys read from the configuration file: ``d_model``, ``n_layer``,
 ``norm_epsilon``, ``tie_embeddings`` and, in ``ssm_cfg``, ``d_state``,
 ``d_conv``, ``expand``, ``headdim`` and ``ngroups``.
+
+Work is counted with the recurrent state in float32, and the prefill's
+scan as the chunked SSD algorithm at the configuration's published
+``chunk_size``, not at any chunk the program picks, so a change of chunk
+cannot move the yardstick (``decode_work``, ``prefill_work``).
 """
 from __future__ import annotations
 
@@ -17,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from . import F32, rms_norm
+from ..work import BF16, F32 as F32_BYTES, Work
 
 
 def _layer_one(layers, i, x, dims, mm):
@@ -76,3 +82,51 @@ def head(cfg: dict, params) -> jax.Array:
     if cfg["tie_embeddings"]:
         return params["embedding"].T
     return params["unembed"]
+
+
+def _sizes(cfg: dict):
+    s = cfg["ssm_cfg"]
+    d, L, V = cfg["d_model"], cfg["n_layer"], cfg["vocab_size"]
+    ns, K, hp, ng = s["d_state"], s["d_conv"], s["headdim"], s["ngroups"]
+    di = s["expand"] * d
+    nh = di // hp
+    cd = di + 2 * ng * ns
+    mat = L * (d * (2 * di + 2 * ng * ns + nh) + di * d)
+    small_bf16 = L * (K * cd + cd + di + d) + d     # conv, norms
+    small_f32 = L * 3 * nh                          # A_log, dt_bias, D
+    state_row = L * (nh * ns * hp * F32_BYTES + (K - 1) * cd * BF16)
+    return d, L, V, ns, K, hp, ng, nh, cd, mat, small_bf16, small_f32, state_row
+
+
+def decode_work(cfg: dict, rows: int, ctx_sum: int) -> Work:
+    d, L, V, ns, K, hp, ng, nh, cd, mat, sb, sf, state_row = _sizes(cfg)
+    flops = rows * (2 * (mat + d * V)
+                    + L * (2 * K * cd + 5 * nh * ns * hp))
+    weights = (mat + sb + d * V) * BF16 + sf * F32_BYTES
+    nbytes = weights + rows * (d * BF16 + 2 * state_row)
+    return Work(flops, nbytes)
+
+
+def prefill_work(cfg: dict, rows: int, prompt_len: int) -> Work:
+    S = prompt_len
+    d, L, V, ns, K, hp, ng, nh, cd, mat, sb, sf, state_row = _sizes(cfg)
+    c = min(cfg["ssm_cfg"]["chunk_size"], S)
+    # per token and layer: C B^T once per group (heads of a group share
+    # B and C), the masked product with x, the chunk state and its output
+    ssd = ng * 2 * c * ns + nh * (2 * c * hp + 4 * ns * hp)
+    flops = rows * (S * (2 * mat + L * (2 * K * cd + ssd)) + 2 * d * V)
+    weights = (mat + sb + d * V) * BF16 + sf * F32_BYTES
+    nbytes = weights + rows * (S * d * BF16 + state_row)
+    return Work(flops, nbytes)
+
+
+def program_fields(conf: dict) -> dict:
+    s = conf["ssm_cfg"]
+    return {"d_model": conf["d_model"], "n_layers": conf["n_layer"],
+            "vocab_size": conf["vocab_size"],
+            "norm_eps": conf["norm_epsilon"],
+            "tie_embeddings": conf["tie_embeddings"],
+            "ssm_state": s["d_state"], "conv_kernel": s["d_conv"],
+            "ssm_expand": s["expand"], "ssm_headdim": s["headdim"],
+            "ssm_ngroups": s["ngroups"],
+            "vocab_pad": conf["pad_vocab_size_multiple"]}

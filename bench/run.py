@@ -206,8 +206,9 @@ class Cell:
         if not closed:
             raise SystemExit(f"bench: the queue of {mix.requests} requests "
                              f"drained inside the {seconds} s window")
-        counters = {k: eng.stats[k] - stats0[k] for k in
-                    ("prefill_calls", "nonfinite_tokens", "timeouts")}
+        counters = {k: eng.stats.get(k, 0) - stats0.get(k, 0) for k in
+                    ("prefill_calls", "nonfinite_tokens", "timeouts",
+                     "rows_admitted", "rows_prefilled", "compiles")}
         return Run(conf=self.conf, mix=mix, peak=self.peak or {},
                    setup_s=t_open - T_START, t_open=t_open,
                    t_close=t_open + seconds, served=served,

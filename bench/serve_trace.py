@@ -3,10 +3,11 @@ model's named scopes say (the engine's instrumentation: ``serve.*`` host
 spans, step programs ``jit_prefill_step`` / ``jit_decode_step``, layer
 kinds as ``jax.named_scope`` in each operation's ``tf_op``).
 
-- ``decode_host_s``: for each decode step with no ``serve.prefill``
-  between, the time from the end of its ``serve.sync`` (tokens on the
-  host) to the start of the next ``jit_decode_step`` execution on the
-  device: what the host loop costs the device each step.
+- ``decode_host_s``: for each two ``jit_decode_step`` executions with no
+  ``jit_prefill_step`` between, the device's idle from the end of the
+  first step's last program (the decode and its argmax and finite check)
+  to the start of the second: what the host loop (tokens to the host,
+  emit, the next launch) costs the device each step.
 - ``step_s`` / ``scope_s``: device time of each step program's executions
   and, inside them, operations' self time by innermost layer-kind scope
   (``unscoped`` where the name stack holds none).
@@ -167,19 +168,14 @@ def reduce(raw: dict) -> Optional[ServeReduction]:
         if kind in scope_s:
             scope_s[kind][scope] = ns / 1e9
 
-    decode_starts = [m[1] for m, stem in zip(mods, stems)
-                     if stem == STEPS["decode"]]
-    prefills = [e[1] for e in spans if e[0] == "serve.prefill"]
+    # on the device's clock alone: the host's clock and the device's are
+    # aligned only to a millisecond or so, which differs from trace to trace
+    decode = [i for i, stem in enumerate(stems) if stem == STEPS["decode"]]
     host_s = []
-    for _, _, end, _ in (e for e in spans if e[0] == "serve.sync"):
-        i = bisect.bisect_right(decode_starts, end)
-        if i == len(decode_starts):
-            continue
-        nxt = decode_starts[i]
-        j = bisect.bisect_right(prefills, end)
-        if j < len(prefills) and prefills[j] < nxt:
+    for i, j in zip(decode, decode[1:]):
+        if STEPS["prefill"] in stems[i:j]:
             continue                        # a refill ran in between
-        host_s.append((nxt - end) / 1e9)
+        host_s.append((mods[j][1] - max(m[2] for m in mods[i:j])) / 1e9)
     return ServeReduction(
         window_s=(hi - lo) / 1e9, decode_host_s=host_s, step_s=step_s,
         scope_s=scope_s,

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 import bench_smoke
-from bench import run, traffic, weights
+from bench import reference, run, traffic, weights
 
 ROOT = Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -43,7 +43,7 @@ def test_every_metric_has_a_reader(metric):
 @pytest.mark.parametrize("config", SPEC["configs"], ids=lambda c: c["name"])
 def test_config_file_is_what_the_program_runs(config):
     """The file's program section is the registry's configuration, and
-    its published keys agree with it."""
+    its published keys agree with it, as its family maps them."""
     from repro.configs.registry import get_config
     from repro.models.config import ModelConfig
 
@@ -51,24 +51,8 @@ def test_config_file_is_what_the_program_runs(config):
     cfg = ModelConfig(**conf["program"])
     assert cfg == get_config(conf["name"])
     assert set(config["reduced"]) == set(conf["reduced"]) == set(conf["published"])
-    if conf["reference"] == "dense":
-        assert (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads,
-                cfg.d_ff, cfg.vocab_size, cfg.rope_theta, cfg.norm_eps,
-                cfg.tie_embeddings) == (
-            conf["hidden_size"], conf["num_hidden_layers"],
-            conf["num_attention_heads"], conf["num_key_value_heads"],
-            conf["intermediate_size"], conf["vocab_size"], conf["rope_theta"],
-            conf["rms_norm_eps"], conf["tie_word_embeddings"])
-    else:
-        s = conf["ssm_cfg"]
-        assert (cfg.d_model, cfg.n_layers, cfg.vocab_size, cfg.norm_eps,
-                cfg.tie_embeddings, cfg.ssm_state, cfg.conv_kernel,
-                cfg.ssm_expand, cfg.ssm_headdim, cfg.ssm_ngroups,
-                cfg.vocab_pad) == (
-            conf["d_model"], conf["n_layer"], conf["vocab_size"],
-            conf["norm_epsilon"], conf["tie_embeddings"], s["d_state"],
-            s["d_conv"], s["expand"], s["headdim"], s["ngroups"],
-            conf["pad_vocab_size_multiple"])
+    want = reference.part(conf["reference"], "program_fields")(conf)
+    assert {field: getattr(cfg, field) for field in want} == want
 
 
 def test_a_new_traffic_file_is_found_by_name(tmp_path):

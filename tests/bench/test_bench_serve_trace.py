@@ -51,9 +51,45 @@ def _raw():
 
 def test_host_time_per_decode_step_skips_steps_with_a_refill():
     r = serve_trace.reduce(_raw())
-    # sync ends 150, next decode starts 225: 75 ns; the step ending 260
-    # refilled before its next decode; the last has no next decode
-    assert r.decode_host_s == pytest.approx([75e-9])
+    # the decode ending 148 is followed by one at 225: 77 ns idle; a
+    # prefill runs between the next two; the last has no next decode
+    assert r.decode_host_s == pytest.approx([77e-9])
+
+
+def test_host_time_per_decode_step_is_read_on_the_device_clock():
+    """The host's spans sit some tenths of a ms off the device's clock, by
+    an amount that differs from trace to trace: here each execution shows
+    0.3 ms before the span that launched it, so the second decode starts
+    before the sync that precedes its launch has ended."""
+    ms = 1_000_000
+    host = [("bench.window", 0, 100 * ms, {}),
+            ("serve.prefill", 0.4 * ms, 0.6 * ms, {"rows": 2, "batch": 2}),
+            ("serve.decode", 1.3 * ms, 1.5 * ms, {"rows": 2}),
+            ("serve.sync", 1.5 * ms, 11.8 * ms, {}),
+            ("serve.decode", 11.8 * ms, 12.0 * ms, {"rows": 2}),
+            ("serve.sync", 12.0 * ms, 21.8 * ms, {}),
+            ("serve.prefill", 21.9 * ms, 22.1 * ms, {"rows": 1, "batch": 1}),
+            ("serve.splice", 22.1 * ms, 22.3 * ms, {}),
+            ("serve.decode", 22.3 * ms, 22.5 * ms, {"rows": 2}),
+            ("serve.sync", 22.5 * ms, 35.8 * ms, {}),
+            ("serve.decode", 35.9 * ms, 36.1 * ms, {"rows": 2}),
+            ("serve.sync", 36.1 * ms, 45.8 * ms, {})]
+    mods = [("jit_prefill_step(1)", 0.2 * ms, 0.9 * ms),
+            ("jit_decode_step(2)", 1.1 * ms, 11.0 * ms),
+            ("jit_argmax(3)", 11.0 * ms, 11.05 * ms),
+            ("jit_decode_step(2)", 11.5 * ms, 21.0 * ms),
+            ("jit_argmax(3)", 21.0 * ms, 21.05 * ms),
+            ("jit_prefill_step(1)", 21.6 * ms, 25.0 * ms),
+            ("jit_splice_rows(4)", 25.0 * ms, 25.2 * ms),
+            ("jit_decode_step(2)", 25.2 * ms, 35.0 * ms),
+            ("jit_argmax(3)", 35.0 * ms, 35.05 * ms),
+            ("jit_decode_step(2)", 35.6 * ms, 45.0 * ms)]
+    raw = {"host": host, "devices": [{"name": "/device:TPU:0",
+                                      "modules": mods, "ops": []}]}
+    r = serve_trace.reduce(raw)
+    # from each argmax's end to the next decode's start; the refill's
+    # step is left out
+    assert r.decode_host_s == pytest.approx([0.45e-3, 0.55e-3])
 
 
 def test_mixer_shares_are_scope_self_time_over_step_device_time():
@@ -142,6 +178,7 @@ def test_recorded_trace_of_the_instrumented_engine(monkeypatch, tmp_path):
     assert 0 < r.mixer_share("prefill") < 100
     assert 0 < r.mixer_share("decode") < 100
     assert 0 < min(r.decode_host_s) and len(r.decode_host_s) > 10
+    assert max(r.decode_host_s) < 1e-3          # none spans a step (5 ms)
     assert sum(s for label, s in r.idle_by_span
                if label.startswith("host in serve.")) > 0.5 * sum(
         s for _, s in r.idle_by_span)
