@@ -10,8 +10,11 @@ collective primitives (``psum``/``pmax``/``pmin``/``all_gather``/
 ``psum_scatter``/``all_to_all``/``ppermute``) the same scan-aware way,
 recording per-(type, participants) occurrence counts and data volumes.
 
-Counted FLOPs: ``dot_general`` (2·M·N·K·batch) and ``conv_general_dilated``
-(2 · output points · kernel spatial · in-channels-per-group).  Elementwise/
+Counted FLOPs: ``dot_general`` (2·M·N·K·batch), ``ragged_dot_general``
+(2 · lhs elements · the rhs's free size: every lhs row meets one group,
+over the rows it is given, whether or not a group claims them) and
+``conv_general_dilated`` (2 · output points · kernel spatial ·
+in-channels-per-group).  Elementwise/
 reduce FLOPs are a few percent of LM totals and are not counted
 (documented).  Returned FLOPs are GLOBAL (whole-program,
 pre-partitioning): divide by the device count for per-device numbers.
@@ -189,6 +192,18 @@ def _dot_flops(eqn) -> float:
     return 2.0 * batch * m * n * contract
 
 
+def _ragged_dot_flops(eqn) -> float:
+    a, b = eqn.invars[0].aval, eqn.invars[1].aval
+    rdims = eqn.params["ragged_dot_dimension_numbers"]
+    (_, rc), (_, rb) = rdims.dot_dimension_numbers
+    bound = set(rc) | set(rb) | set(rdims.rhs_group_dimensions)
+    n = 1
+    for i, s in enumerate(b.shape):
+        if i not in bound:
+            n *= s
+    return 2.0 * a.size * n
+
+
 def _conv_flops(eqn) -> float:
     """conv_general_dilated as a dot per output point: every output element
     is a (kernel-spatial × in-channels-per-group) MAC reduction."""
@@ -344,6 +359,8 @@ def _walk(jaxpr, flops_mult: float, coll_mult: float,
         prim = eqn.primitive.name
         if prim == "dot_general":
             out.flops += flops_mult * _dot_flops(eqn)
+        elif prim == "ragged_dot_general":
+            out.flops += flops_mult * _ragged_dot_flops(eqn)
         elif prim == "conv_general_dilated":
             out.flops += flops_mult * _conv_flops(eqn)
         elif prim in _PRIM_TO_TYPE:

@@ -30,6 +30,12 @@ _MODULES = {
 
 ARCH_IDS: List[str] = list(_MODULES)
 
+# one chip's share of a registry architecture, as a benchmark cell serves
+# it: ``get_config`` resolves these (module, function); no dry-run cells
+_SHARES = {
+    "deepseek-v3-ep32": ("deepseek_v3_671b", "ep32_config"),
+}
+
 
 @dataclass(frozen=True)
 class Shape:
@@ -55,6 +61,9 @@ def _mod(arch_id: str):
 
 
 def get_config(arch_id: str) -> ModelConfig:
+    if arch_id in _SHARES:
+        module, fn = _SHARES[arch_id]
+        return getattr(importlib.import_module(f"repro.configs.{module}"), fn)()
     return _mod(arch_id).config()
 
 

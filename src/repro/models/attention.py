@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from ..kernels import ops as kops
 from .config import ModelConfig
-from .layers import apply_norm, apply_rope, rope_cos_sin
+from .layers import apply_norm, apply_rope, rope_cos_sin, yarn_mscale
 from .param import ParamSpec
 
 F32 = jnp.float32
@@ -211,7 +211,7 @@ def _qkv(cfg: ModelConfig, p, x, positions):
             kn["bias"] = jnp.zeros_like(p["k_norm"])
         q = apply_norm(cfg, qn, q)
         k = apply_norm(cfg, kn, k)
-    cos, sin = rope_cos_sin(positions, hd, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions, hd, cfg)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     return q, k, v
@@ -314,6 +314,12 @@ def attn_decode(cfg: ModelConfig, p, x, cache: Dict, pos: jax.Array
 # =============================================================== MLA paths
 
 
+def _mla_scale(cfg) -> float:
+    """Softmax scale over the (nope + rope) query width, times YaRN's
+    mscale squared."""
+    return yarn_mscale(cfg) ** 2 / math.sqrt(cfg.hd + cfg.rope_head_dim)
+
+
 def _mla_q(cfg, p, x, positions):
     B, S, _ = x.shape
     H, dn, dr = cfg.n_heads, cfg.hd, cfg.rope_head_dim
@@ -321,7 +327,7 @@ def _mla_q(cfg, p, x, positions):
     cq = apply_norm(cfg.with_(norm_type="rmsnorm"), {"scale": p["q_norm"]}, cq)
     q = (cq @ p["wuq"]).reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    cos, sin = rope_cos_sin(positions, dr, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions, dr, cfg)
     q_rope = apply_rope(q_rope, cos, sin)
     return q_nope, q_rope
 
@@ -331,7 +337,7 @@ def _mla_kv_compress(cfg, p, x, positions):
     ckv_full = x @ p["wdkv"]                                   # (B,S,kvr+dr)
     ckv, kr = ckv_full[..., :kvr], ckv_full[..., kvr:]
     ckv = apply_norm(cfg.with_(norm_type="rmsnorm"), {"scale": p["kv_norm"]}, ckv)
-    cos, sin = rope_cos_sin(positions, dr, cfg.rope_theta)
+    cos, sin = rope_cos_sin(positions, dr, cfg)
     kr = apply_rope(kr[:, :, None, :], cos, sin)[:, :, 0, :]   # shared across heads
     return ckv, kr
 
@@ -347,9 +353,9 @@ def _mla_train(cfg, p, x, *, return_cache: bool = False):
     q = jnp.concatenate([q_nope, q_rope], -1)                  # (B,S,H,dn+dr)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(kr[:, :, None, :],
                                                   (B, S, H, dr))], -1)
-    scale = 1.0 / math.sqrt(dn + dr)
     o = _attend(cfg, q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), causal=True, window=None, scale=scale)
+                v.transpose(0, 2, 1, 3), causal=True, window=None,
+                scale=_mla_scale(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(B, S, H * dv)
     out = o @ p["wo"]
     if return_cache:
@@ -379,7 +385,7 @@ def _mla_decode(cfg, p, x, cache, pos):
     s = jnp.einsum("bhk,bsk->bhs", q_lat, ckv.astype(F32))
     s = s + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0].astype(F32),
                        kr.astype(F32))
-    s = s * (1.0 / math.sqrt(dn + dr))
+    s = s * _mla_scale(cfg)
     mask = jnp.arange(S)[None, :] <= pos[:, None]              # (B, S)
     s = jnp.where(mask[:, None, :], s, NEG)
     pr = jax.nn.softmax(s, axis=-1)

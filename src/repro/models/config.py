@@ -26,6 +26,7 @@ class ModelConfig:
     # --- attention ---
     attn_type: str = "gqa"           # gqa | mla | none (ssm) | parallel (hybrid)
     rope_theta: float = 10000.0
+    rope_factor: float = 1.0         # YaRN's scaling factor (MLA); 1: plain RoPE
     qk_norm: bool = False
     window: Optional[int] = None     # sliding-window width (None = global)
     # MLA (DeepSeek)
@@ -35,13 +36,18 @@ class ModelConfig:
     v_head_dim: int = 0
 
     # --- FFN / MoE ---
-    n_experts: int = 0
+    n_experts: int = 0               # the router's outputs
+    n_experts_held: int = 0          # experts held here, the first of the
+                                     # router's (an expert-parallel share);
+                                     # 0: all of them
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
     first_dense_layers: int = 0      # DeepSeek: leading dense layers
     router_type: str = "softmax"     # softmax | sigmoid (DeepSeek noaux bias)
-    capacity_factor: float = 1.25
+    n_group: int = 1                 # sigmoid router: experts in n_group
+    topk_group: int = 1              # groups, top-k taken in the best topk_group
+    routed_scaling: float = 1.0      # multiplies the normalised gates
 
     # --- SSM (Mamba-2) ---
     ssm_state: int = 0
@@ -79,6 +85,10 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def padded_vocab(self) -> int:
@@ -132,7 +142,7 @@ class ModelConfig:
             att = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
         ffn_dense = 3 * d * self.d_ff
         if self.is_moe:
-            ffn = (self.n_experts + self.n_shared_experts) * 3 * d * self.moe_d_ff \
+            ffn = (self.experts_held + self.n_shared_experts) * 3 * d * self.moe_d_ff \
                 + d * self.n_experts
             dense_part = self.first_dense_layers * ffn_dense
             moe_part = (L - self.first_dense_layers) * ffn
@@ -158,7 +168,8 @@ class ModelConfig:
             return self.n_params()
         d, L = self.d_model, self.n_layers
         full = self.n_params()
-        all_experts = (L - self.first_dense_layers) * self.n_experts * 3 * d * self.moe_d_ff
-        active = (L - self.first_dense_layers) * (self.top_k + self.n_shared_experts) \
+        all_experts = (L - self.first_dense_layers) * self.experts_held * 3 * d * self.moe_d_ff
+        routed = self.top_k * self.experts_held / self.n_experts
+        active = (L - self.first_dense_layers) * (routed + self.n_shared_experts) \
             * 3 * d * self.moe_d_ff
         return int(full - all_experts + active)

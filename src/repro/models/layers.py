@@ -1,16 +1,18 @@
 """Shared model layers: norms, RoPE, MLP, embeddings, loss."""
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .config import ModelConfig
 from .param import ParamSpec
 
 __all__ = [
-    "norm_specs", "apply_norm", "rope_cos_sin", "apply_rope",
+    "norm_specs", "apply_norm", "rope_cos_sin", "yarn_mscale", "apply_rope",
     "mlp_specs", "mlp_apply", "embed_specs", "embed_apply", "unembed_apply",
     "cross_entropy_loss",
 ]
@@ -48,12 +50,48 @@ def apply_norm(cfg: ModelConfig, p: Dict[str, jax.Array], x: jax.Array) -> jax.A
 # -------------------------------------------------------------------- RoPE
 
 
-def rope_cos_sin(positions: jax.Array, dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
-    """positions: (...,) int -> cos/sin (..., dim//2) f32."""
+def rope_cos_sin(positions: jax.Array, dim: int, cfg: ModelConfig
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """positions: (...,) int -> cos/sin (..., dim//2) f32, at
+    ``cfg.rope_theta``; where ``cfg.rope_factor`` is above 1 the
+    frequencies are YaRN's."""
     half = dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=F32) / half))
+    freqs = 1.0 / (cfg.rope_theta ** (jnp.arange(half, dtype=F32) / half))
+    if cfg.rope_factor > 1:
+        keep = _yarn_keep(cfg, dim)
+        freqs = freqs * keep + freqs / cfg.rope_factor * (1 - keep)
     ang = positions.astype(F32)[..., None] * freqs
     return jnp.cos(ang), jnp.sin(ang)
+
+
+# YaRN's other settings, as DeepSeek-V3 publishes them (``rope_scaling``):
+# the context it was trained at, the turns over it that bound the ramp,
+# and ``mscale_all_dim``.  Only ``rope_factor`` is a field; a model with
+# other settings needs them as fields too (the benchmark's deepseek_v3
+# family refuses a file whose ``rope_scaling`` differs from these)
+YARN_ORIGINAL_LEN, YARN_BETA_FAST, YARN_BETA_SLOW, YARN_MSCALE = 4096, 32, 1, 1.0
+
+
+def _yarn_keep(cfg: ModelConfig, dim: int) -> np.ndarray:
+    """YaRN's share of each of the ``dim // 2`` rope frequencies kept as
+    is (1 for the fast ones, which turn more than ``YARN_BETA_FAST`` times
+    over ``YARN_ORIGINAL_LEN`` positions; 0 for those slower than
+    ``YARN_BETA_SLOW`` turns, divided by ``rope_factor``; linear between)."""
+    def at(turns):          # the frequency index that turns ``turns`` times
+        return dim * math.log(YARN_ORIGINAL_LEN / (turns * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_theta))
+    lo = max(math.floor(at(YARN_BETA_FAST)), 0)
+    hi = min(math.ceil(at(YARN_BETA_SLOW)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0, 1)
+    return (1 - ramp).astype(np.float32)
+
+
+def yarn_mscale(cfg: ModelConfig) -> float:
+    """YaRN's attention temperature: the softmax scale is multiplied by its
+    square (1 without scaling)."""
+    if cfg.rope_factor <= 1:
+        return 1.0
+    return 0.1 * YARN_MSCALE * math.log(cfg.rope_factor) + 1.0
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -304,7 +304,7 @@ def train_collective_schedule(cfg, mesh: Mesh, batch: int, seq: int, *,
     n_moe = (cfg.n_layers - cfg.first_dense_layers) if cfg.is_moe else 0
     if n_moe and P_m > 1:
         E = cfg.n_experts
-        e_local = E // P_m
+        e_local = cfg.experts_held // P_m
         f = cfg.moe_d_ff
         t_local = (b * seq) // max(P_dp, 1)
         # combine psum (fwd) + its transpose + the x cotangent psum
@@ -325,10 +325,6 @@ def train_collective_schedule(cfg, mesh: Mesh, batch: int, seq: int, *,
             sched.append(DeclaredCollective(
                 "moe/router-grad", "AllReduce",
                 D * E * 4.0, P_dp * P_m, 1 * n_moe * m))
-            if cfg.router_type == "sigmoid":
-                sched.append(DeclaredCollective(
-                    "moe/router-bias-grad", "AllReduce",
-                    E * 4.0, P_dp * P_m, 1 * n_moe * m))
 
     # ---- GSPMD-owned collectives (priced + HLO-reconciled only) --------
     # Data-axis gradient all-reduces, sized from the real param tree.

@@ -103,6 +103,20 @@ def test_structural_flops_batched_einsum():
     assert structural_flops(f, A, B) == 2 * (2 * 3) * 8 * 5 * 4
 
 
+def test_structural_flops_ragged_dot_and_its_grads():
+    """Every lhs row meets one group: 2·M·K·N, and each of the two
+    gradients (rows against groups, and the ragged contraction that
+    gives the groups' weights) costs the same again."""
+    X = jax.ShapeDtypeStruct((64, 32), jnp.float32)
+    W = jax.ShapeDtypeStruct((4, 32, 16), jnp.float32)
+    G = jax.ShapeDtypeStruct((4,), jnp.int32)
+    f = lambda x, w, g: jax.lax.ragged_dot(x, w, g)
+    assert structural_flops(f, X, W, G) == 2 * 64 * 32 * 16
+    grads = lambda x, w, g: jax.grad(
+        lambda x, w: jax.lax.ragged_dot(x, w, g).sum(), argnums=(0, 1))(x, w)
+    assert structural_flops(grads, X, W, G) == 3 * 2 * 64 * 32 * 16
+
+
 def test_structural_flops_model_consistency():
     """glm4 smoke: train-step structural FLOPs ≈ 8·N·D (full remat:
     fwd + recompute + 2x bwd) within 35% (attention/vocab overheads)."""

@@ -161,9 +161,7 @@ REFILL_B = 8
 # one dense, SSM, hybrid (window 8 < cache: the ring wraps) and MLA config
 # with a leading dense stack, in float32: in bfloat16 a near-tie can pick
 # another greedy token at another batch shape, however rows are refilled.
-# The MoE gets a capacity of every token (E / top_k): capacity-bounded
-# dispatch drops tokens by what else shares the batch, so only a dropless
-# router lets a row's tokens be compared with a solo run.
+# The MoE is dropless, so a row's tokens can be compared with a solo run.
 REFILL_FAMILIES = {"glm4-9b": {}, "mamba2-130m": {},
                    "hymba-1.5b": {"window": 8}, "deepseek-v3-671b": {}}
 # budgets that free 1, then 3, then all 8 slots at once, then 1, then 6
@@ -190,8 +188,6 @@ def refill_engine(request):
     from repro.serve.engine import ServeEngine
     cfg = get_smoke_config(request.param).with_(
         dtype="float32", **REFILL_FAMILIES[request.param])
-    if cfg.is_moe:
-        cfg = cfg.with_(capacity_factor=cfg.n_experts / cfg.top_k)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     return ServeEngine(model, params, batch_size=REFILL_B, cache_len=48,

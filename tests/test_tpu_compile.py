@@ -243,3 +243,30 @@ def test_refill_prefills_a_bucket_and_splices_in_place(one_chip):
     assert ma.alias_size_in_bytes >= state + REFILL_BATCH * 4
     assert ma.output_size_in_bytes - ma.alias_size_in_bytes < state // 100
     assert ma.temp_size_in_bytes < state // 100
+
+
+# the deepseek-v3.chat cell: one chip's EP32 share of DeepSeek-V3 (8.66 GB
+# of bf16 weights) at 64 slots of 256-token prompts, a 1280-position cache
+EP32_ARCH, EP32_BATCH, EP32_PROMPT, EP32_CACHE = (
+    "deepseek-v3-ep32", 64, 256, 1280)
+
+
+def test_expert_share_fills_and_decodes_on_one_chip(one_chip):
+    """At published widths, the first fill of all 64 slots (the MLA
+    prefill decompresses K and V for 16384 tokens) and the decode step
+    of the 7-layer chip share each fit one chip's memory."""
+    from repro.configs.registry import get_config
+    from repro.models.model import Model
+    from repro.serve.engine import ServeEngine
+
+    cfg = get_config(EP32_ARCH)
+    params = _on(one_chip, Model(cfg).abstract_params())
+    eng = ServeEngine(Model(cfg), params, batch_size=EP32_BATCH,
+                      cache_len=EP32_CACHE, prompt_len=EP32_PROMPT,
+                      plan_warmup=False)
+    batch = {"tokens": jax.ShapeDtypeStruct((EP32_BATCH, EP32_PROMPT),
+                                            jnp.int32, sharding=one_chip)}
+    assert _fits_one_chip(eng.prefill_step.lower(params, batch).compile())
+    _, cache = _on(one_chip, jax.eval_shape(eng.prefill_step, params, batch))
+    tok = jax.ShapeDtypeStruct((EP32_BATCH, 1), jnp.int32, sharding=one_chip)
+    assert _fits_one_chip(eng.decode_step.lower(params, cache, tok).compile())
