@@ -278,37 +278,45 @@ def attn_prefill(cfg: ModelConfig, p, x) -> Tuple[jax.Array, Dict]:
     return o @ p["wo"], cache
 
 
-def attn_decode(cfg: ModelConfig, p, x, cache: Dict, pos: jax.Array
-                ) -> Tuple[jax.Array, Dict]:
-    """One-token decode.  x: (B, 1, d); pos: scalar int32 or per-row
-    (B,) int32 (per-slot positions — continuous-batching engines
-    re-prefill individual slots, so rows may sit at different depths)."""
+def _layer(a: jax.Array, i: jax.Array) -> jax.Array:
+    """Layer ``i`` of a stacked cache leaf (L, ...)."""
+    return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+
+
+def attn_decode(cfg: ModelConfig, p, x, cache: Dict, i: jax.Array,
+                pos: jax.Array) -> Tuple[jax.Array, Dict]:
+    """One-token decode of layer ``i``.  x: (B, 1, d); ``cache`` is the
+    layer-stacked cache (leading axis L), into which the new position is
+    written in place and from which layer ``i`` is read; it is returned
+    with only that position changed.  pos: per-row (B,) int32 (per-slot
+    positions — continuous-batching engines re-prefill individual slots,
+    so rows may sit at different depths)."""
     if cfg.attn_type == "mla":
-        return _mla_decode(cfg, p, x, cache, pos)
+        return _mla_decode(cfg, p, x, cache, i, pos)
     B = x.shape[0]
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     q, k1, v1 = _qkv(cfg, p, x, pos[:, None])      # per-row RoPE positions
-    W = cache["k"].shape[1]
+    W = cache["k"].shape[2]
     slot = pos % W
     rows = jnp.arange(B)
-    k = cache["k"].at[rows, slot].set(k1[:, 0])
-    v = cache["v"].at[rows, slot].set(v1[:, 0])
-    kpos = cache["kpos"].at[rows, slot].set(pos)               # (B, W)
+    cache = {"k": cache["k"].at[i, rows, slot].set(k1[:, 0]),
+             "v": cache["v"].at[i, rows, slot].set(v1[:, 0]),
+             "kpos": cache["kpos"].at[i, rows, slot].set(pos)}
+    k, v, kpos = (_layer(cache[n], i) for n in ("k", "v", "kpos"))
     scale = 1.0 / math.sqrt(hd)
     group = H // Hkv
     qg = q.astype(F32).reshape(B, Hkv, group, hd)              # grouped layout
     kf = k.astype(F32)
     vf = v.astype(F32)
     s = jnp.einsum("bhgd,bshd->bhgs", qg, kf) * scale
-    valid = (kpos >= 0) & (kpos <= pos[:, None])
+    valid = (kpos >= 0) & (kpos <= pos[:, None])               # (B, W)
     if cfg.window:
         valid = valid & (kpos > (pos - cfg.window)[:, None])
     s = jnp.where(valid[:, None, None, :], s, NEG)
     pr = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgs,bshd->bhgd", pr, vf).astype(x.dtype)
     o = o.reshape(B, 1, H * hd)
-    return o @ p["wo"], {"k": k, "v": v, "kpos": kpos}
+    return o @ p["wo"], cache
 
 
 # =============================================================== MLA paths
@@ -363,20 +371,21 @@ def _mla_train(cfg, p, x, *, return_cache: bool = False):
     return out
 
 
-def _mla_decode(cfg, p, x, cache, pos):
-    """Absorbed MLA decode: attention runs in the latent (kv_lora) space —
-    the compressed cache is never decompressed (DeepSeek inference opt.).
-    ``pos`` may be scalar or per-row (B,) (per-slot decode depths)."""
+def _mla_decode(cfg, p, x, cache, i, pos):
+    """Absorbed MLA decode of layer ``i``: attention runs in the latent
+    (kv_lora) space — the compressed cache is never decompressed (DeepSeek
+    inference opt.).  The new position is written into the stacked latent
+    cache in place, as in ``attn_decode``; ``pos`` is per-row (B,)."""
     B = x.shape[0]
     H, dn, dr, dv = cfg.n_heads, cfg.hd, cfg.rope_head_dim, cfg.v_head_dim
     kvr = cfg.kv_lora_rank
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     posv = pos[:, None]                                        # (B,1)
     q_nope, q_rope = _mla_q(cfg, p, x, posv)                   # (B,1,H,*)
     ckv1, kr1 = _mla_kv_compress(cfg, p, x, posv)              # (B,1,kvr),(B,1,dr)
     rows = jnp.arange(B)
-    ckv = cache["ckv"].at[rows, pos].set(ckv1[:, 0])
-    kr = cache["kr"].at[rows, pos].set(kr1[:, 0])
+    cache = {"ckv": cache["ckv"].at[i, rows, pos].set(ckv1[:, 0]),
+             "kr": cache["kr"].at[i, rows, pos].set(kr1[:, 0])}
+    ckv, kr = _layer(cache["ckv"], i), _layer(cache["kr"], i)
     S = ckv.shape[1]
     wuk = p["wuk"].reshape(kvr, H, dn)
     # absorb: q_lat[b,h,:] = W_uk[:,h,:] @ q_nope[b,h,:]
@@ -393,7 +402,7 @@ def _mla_decode(cfg, p, x, cache, pos):
     wuv = p["wuv"].reshape(kvr, H, dv)
     o = jnp.einsum("bhk,khd->bhd", ctx_lat, wuv.astype(F32)).astype(x.dtype)
     o = o.reshape(B, 1, H * dv)
-    return o @ p["wo"], {"ckv": ckv, "kr": kr}
+    return o @ p["wo"], cache
 
 
 # ============================================================ cross-attn

@@ -153,18 +153,21 @@ def encdec_decode(cfg: ModelConfig, params: Dict, cache: Dict,
     x = embed_apply(params, tokens).astype(jnp.dtype(cfg.dtype))
 
     def body(carry, xs):
-        pl, cs, cc = xs
-        h, nc = attn.attn_decode(cfg, pl["attn"],
-                                 apply_norm(cfg, pl["norm1"], carry), cs, pos)
-        carry = carry + h
-        carry = carry + attn.cross_decode(cfg, pl["cross"],
-                                          apply_norm(cfg, pl["norm_x"], carry), cc)
-        carry = carry + mlp_apply(cfg, pl["mlp"],
-                                  apply_norm(cfg, pl["norm2"], carry))
-        return carry, nc
+        # the self-attention cache rides in the carry and is written in
+        # place (see transformer._scan_decode); the cross cache is read only
+        x, cs = carry
+        pl, cc, i = xs
+        h, cs = attn.attn_decode(cfg, pl["attn"], apply_norm(cfg, pl["norm1"], x),
+                                 cs, i, pos)
+        x = x + h
+        x = x + attn.cross_decode(cfg, pl["cross"],
+                                  apply_norm(cfg, pl["norm_x"], x), cc)
+        x = x + mlp_apply(cfg, pl["mlp"], apply_norm(cfg, pl["norm2"], x))
+        return (x, cs), None
 
-    x, new_self = jax.lax.scan(body, x, (params["dec_layers"], cache["self"],
-                                         cache["cross"]))
+    (x, new_self), _ = jax.lax.scan(
+        body, (x, cache["self"]),
+        (params["dec_layers"], cache["cross"], jnp.arange(cfg.n_layers)))
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed_apply(cfg, params, x)
     logits = constrain(logits, mesh, P(dp if dp else None, None, "model"))

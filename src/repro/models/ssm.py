@@ -154,24 +154,28 @@ def ssm_prefill(cfg: ModelConfig, p, x: jax.Array) -> Tuple[jax.Array, Dict]:
     return out, {"conv": conv_state, "state": state}
 
 
-def ssm_decode(cfg: ModelConfig, p, x: jax.Array, cache: Dict
-               ) -> Tuple[jax.Array, Dict]:
-    """One-token step.  x: (B, 1, d)."""
+def ssm_decode(cfg: ModelConfig, p, x: jax.Array, cache: Dict,
+               i: jax.Array) -> Tuple[jax.Array, Dict]:
+    """One-token step of layer ``i``.  x: (B, 1, d); ``cache`` is the
+    layer-stacked cache (leading axis L): layer ``i``'s conv window and
+    state are read from it and their successors written back in place."""
     B_, _, _ = x.shape
     di, nh, hp, ns = cfg.d_inner, cfg.ssm_nheads, cfg.ssm_headdim, cfg.ssm_state
     proj = x @ p["in_proj"]
     z, xin, Bm, Cm, dt_raw = _split_proj(cfg, proj)
     xbc1 = jnp.concatenate([xin, Bm, Cm], axis=-1)            # (B,1,cd)
-    conv_prev = cache["conv"]
+    conv_prev = jax.lax.dynamic_index_in_dim(cache["conv"], i, 0, False)
     xbc = _conv1d(cfg, p["conv_w"], p["conv_b"], xbc1, prev=conv_prev)
     new_conv = jnp.concatenate([conv_prev, xbc1], axis=1)[:, 1:, :]
     xh, xdt, dA, Bh, Ch = _ssd_inputs(cfg, p, xbc, dt_raw)
     # recurrence: h' = exp(dA) h + B ⊗ xdt
-    h = cache["state"]
+    h = jax.lax.dynamic_index_in_dim(cache["state"], i, 0, False)
     h = jnp.exp(dA[:, 0, :, None, None]) * h \
         + jnp.einsum("bhn,bhp->bhnp", Bh[:, 0].astype(F32), xdt[:, 0])
     y = jnp.einsum("bhn,bhnp->bhp", Ch[:, 0].astype(F32), h)  # (B,nh,hp)
     y = y + xh[:, 0].astype(F32) * p["D"].astype(F32)[None, :, None]
     y = y.reshape(B_, 1, di).astype(x.dtype)
     y = _gated_norm(cfg, p["norm_scale"], y, z)
-    return y @ p["out_proj"], {"conv": new_conv, "state": h}
+    put = jax.lax.dynamic_update_index_in_dim
+    return y @ p["out_proj"], {"conv": put(cache["conv"], new_conv, i, 0),
+                               "state": put(cache["state"], h, i, 0)}

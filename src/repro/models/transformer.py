@@ -329,12 +329,26 @@ def _pad_cache(cfg: ModelConfig, c: Dict, S: int, cache_len: int) -> Dict:
 # ----------------------------------------------------------------- decode
 
 
-def _layer_decode(cfg, mesh, use_moe, x, pl, cl, pos):
-    h = _norm(cfg, pl["norm1"], x)
-    x, new_c = _mix(
-        cfg, pl, x, lambda: attn.attn_decode(cfg, pl["attn"], h, cl["attn"], pos),
-        lambda: ssm_mod.ssm_decode(cfg, pl["ssm"], h, cl["ssm"]))
-    return _ffn(cfg, mesh, use_moe, pl, x), new_c
+def _scan_decode(cfg, mesh, use_moe, x, stacked, cache, pos):
+    """One token through a layer stack.  The stack's cache rides in the
+    scan's carry beside the residual, so each layer writes only what it
+    changes into the donated buffer, in place: no cache leaf is a scan
+    input or output, which would slice every layer out, write a second
+    stacked cache and copy it whole."""
+    def body(carry, xs):
+        x, c = carry
+        pl, i = xs
+        h = _norm(cfg, pl["norm1"], x)
+        x, c = _mix(
+            cfg, pl, x,
+            lambda: attn.attn_decode(cfg, pl["attn"], h, c["attn"], i, pos),
+            lambda: ssm_mod.ssm_decode(cfg, pl["ssm"], h, c["ssm"], i))
+        return (_ffn(cfg, mesh, use_moe, pl, x), c), None
+
+    L = jax.tree.leaves(stacked)[0].shape[0]
+    (x, cache), _ = jax.lax.scan(body, (x, cache), (stacked, jnp.arange(L)),
+                                 unroll=_unroll(cfg, L))
+    return x, cache
 
 
 def decode(cfg: ModelConfig, params: Dict, cache: Dict, tokens: jax.Array,
@@ -352,30 +366,13 @@ def decode(cfg: ModelConfig, params: Dict, cache: Dict, tokens: jax.Array,
     x = _embed(cfg, params, tokens)
     x = constrain(x, mesh, P(dp if dp else None, None, None))
     new_cache: Dict[str, Any] = {"pos": pos + 1}
-
-    def make_body(c, use_moe):
-        def body(carry, xs):
-            pl, cl = xs
-            x2, nc = _layer_decode(c, mesh, use_moe, carry, pl, cl, pos)
-            return x2, nc
-        return body
-
     if cfg.first_dense_layers > 0:
-        dense_cfg = cfg.with_(n_experts=0)
-        x, nd = jax.lax.scan(make_body(dense_cfg, False), x,
-                             (params["dense_layers"], cache["dense"]),
-                             unroll=_unroll(cfg, cfg.first_dense_layers))
-        new_cache["dense"] = nd
-        x, nl = jax.lax.scan(make_body(cfg, True), x,
-                             (params["layers"], cache["layers"]),
-                             unroll=_unroll(cfg, cfg.n_layers
-                                            - cfg.first_dense_layers))
-        new_cache["layers"] = nl
-    else:
-        x, nl = jax.lax.scan(make_body(cfg, cfg.is_moe), x,
-                             (params["layers"], cache["layers"]),
-                             unroll=_unroll(cfg, cfg.n_layers))
-        new_cache["layers"] = nl
+        x, new_cache["dense"] = _scan_decode(
+            cfg.with_(n_experts=0), mesh, False, x, params["dense_layers"],
+            cache["dense"], pos)
+    x, new_cache["layers"] = _scan_decode(cfg, mesh, cfg.is_moe, x,
+                                          params["layers"], cache["layers"],
+                                          pos)
     logits = _head(cfg, params, x)
     logits = constrain(logits, mesh, P(dp if dp else None, None, "model"))
     return logits, new_cache

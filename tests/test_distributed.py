@@ -125,6 +125,34 @@ SCRIPT = textwrap.dedent("""
     for a, b in zip(jax.tree.leaves(state.params), jax.tree.leaves(restored)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     print("ELASTIC_RESTORE_OK")
+
+    # ---- 7. decode under the mesh: the layer scans carry the cache, which
+    # comes out with the shardings it went in with (kpos excepted: its ring
+    # axis comes out split over 'model' beside a sequence-split K/V)
+    from repro.launch.specs import with_shardings
+    from repro.parallel.sharding import cache_shardings
+    for arch in ("glm4-9b", "mamba2-130m", "deepseek-v3-671b"):
+        m = Model(get_smoke_config(arch))
+        cab = jax.eval_shape(lambda: m.init_cache(8, 32))
+        csh = cache_shardings(cab, mesh, 8)
+        pab = with_shardings(m.abstract_params(),
+                             param_shardings(m.param_axes(),
+                                             m.abstract_params(), mesh))
+        tok = jax.ShapeDtypeStruct((8, 1), jnp.int32,
+                                   sharding=batch_sharding(mesh, 8, 2))
+        with mesh:
+            out = jax.jit(lambda p, c, t: m.decode(p, c, t, mesh),
+                          donate_argnums=(1,)).lower(
+                pab, with_shardings(cab, csh), tok).compile()
+        for (path, want), got, leaf in zip(
+                jax.tree_util.tree_flatten_with_path(csh)[0],
+                jax.tree.leaves(out.output_shardings[1]),
+                jax.tree.leaves(cab)):
+            name = jax.tree_util.keystr(path)
+            if "kpos" not in name:
+                assert want.is_equivalent_to(got, leaf.ndim), \\
+                    (arch, name, want, got)
+    print("DECODE_CACHE_SHARDINGS_OK")
     print("ALL_DISTRIBUTED_OK")
 """)
 

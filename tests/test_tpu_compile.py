@@ -177,6 +177,17 @@ def _fits_one_chip(compiled):
             + ma.temp_size_in_bytes) < V5E_HBM_BYTES
 
 
+def _updates_cache_in_place(compiled, cache):
+    """The decode step writes into the donated cache: its output aliases
+    the cache, and its temporaries hold less than half of it (a layer scan
+    that takes the stacked cache as ``xs`` and gives it back as ``ys``
+    holds a second whole cache, and more)."""
+    ma = compiled.memory_analysis()
+    size = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    return (ma.alias_size_in_bytes >= size
+            and ma.temp_size_in_bytes < size // 2)
+
+
 @pytest.mark.parametrize("use_kernels", [False, True])
 def test_serve_prefill_compiles(serve_shapes, monkeypatch, use_kernels):
     """The engine's prefill step at published widths fits one chip; with
@@ -199,7 +210,9 @@ def test_serve_decode_compiles(serve_shapes, one_chip):
     cache = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
         s.shape, s.dtype, sharding=one_chip), cache)
     tok = jax.ShapeDtypeStruct((SERVE_BATCH, 1), jnp.int32, sharding=one_chip)
-    assert _fits_one_chip(eng.decode_step.lower(params, cache, tok).compile())
+    compiled = eng.decode_step.lower(params, cache, tok).compile()
+    assert _fits_one_chip(compiled)
+    assert _updates_cache_in_place(compiled, cache)
 
 
 # the mamba2.chat benchmark cell: 128 slots of 256-token prompts; a refill
@@ -245,6 +258,26 @@ def test_refill_prefills_a_bucket_and_splices_in_place(one_chip):
     assert ma.temp_size_in_bytes < state // 100
 
 
+def test_decode_updates_the_ssm_state_in_place(one_chip):
+    """At the mamba2.chat cell's 128 slots, the decode step reads and
+    writes the 2.4 GB of f32 SSD state in place, layer by layer."""
+    from repro.configs.registry import get_config
+    from repro.models.model import Model
+    from repro.serve.engine import ServeEngine
+
+    cfg = get_config(REFILL_ARCH)
+    params = _on(one_chip, Model(cfg).abstract_params())
+    eng = ServeEngine(Model(cfg), params, batch_size=REFILL_BATCH,
+                      cache_len=REFILL_CACHE, prompt_len=REFILL_PROMPT,
+                      plan_warmup=False)
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: Model(cfg).init_cache(REFILL_BATCH, REFILL_CACHE)))
+    tok = jax.ShapeDtypeStruct((REFILL_BATCH, 1), jnp.int32,
+                               sharding=one_chip)
+    assert _updates_cache_in_place(
+        eng.decode_step.lower(params, cache, tok).compile(), cache)
+
+
 # the deepseek-v3.chat cell: one chip's EP32 share of DeepSeek-V3 (8.66 GB
 # of bf16 weights) at 64 slots of 256-token prompts, a 1280-position cache
 EP32_ARCH, EP32_BATCH, EP32_PROMPT, EP32_CACHE = (
@@ -269,4 +302,6 @@ def test_expert_share_fills_and_decodes_on_one_chip(one_chip):
     assert _fits_one_chip(eng.prefill_step.lower(params, batch).compile())
     _, cache = _on(one_chip, jax.eval_shape(eng.prefill_step, params, batch))
     tok = jax.ShapeDtypeStruct((EP32_BATCH, 1), jnp.int32, sharding=one_chip)
-    assert _fits_one_chip(eng.decode_step.lower(params, cache, tok).compile())
+    compiled = eng.decode_step.lower(params, cache, tok).compile()
+    assert _fits_one_chip(compiled)
+    assert _updates_cache_in_place(compiled, cache)
